@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.core import Field, SOA, AOS, TargetConfig, aosoa, launch, target_sum
 from repro.apps.ludwig import LudwigConfig, init_state
 from repro.apps.ludwig.driver import (
@@ -960,6 +961,7 @@ def gate_regressions(metrics, tolerance):
 
 
 def main(argv=None):
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fused", action="store_true",
                     help="only the fused-vs-unfused launch-graph comparison")

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.apps.ludwig import gradients as LG
 from repro.core import plan as plan_mod
 from repro.core.stencil import tile_boxes
@@ -115,6 +116,7 @@ def _cost(fn, *args):
 
 
 def main(argv=None):
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write rows + per-kernel metrics to PATH "

@@ -31,6 +31,7 @@ import sys
 
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.roofline import HBM_BW, ICI_LINK_BW
 
 try:
@@ -226,6 +227,7 @@ def gate_measured(metrics):
 
 
 def main(argv=None):
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny lattices + the measured overlap-vs-pre "

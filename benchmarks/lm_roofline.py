@@ -13,6 +13,7 @@ import json
 import os
 
 from .common import csv_row
+from repro.launch.compile_cache import setup_compile_cache
 
 RESULTS = os.environ.get("DRYRUN_RESULTS", "results/dryrun.jsonl")
 
@@ -31,6 +32,7 @@ def load_rows(path=RESULTS):
 
 
 def main():
+    setup_compile_cache()
     data = load_rows()
     out = []
     if not data:

@@ -37,6 +37,7 @@ try:  # runnable both as a module and as a script
 except ImportError:
     from common import csv_row, time_fn
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.apps.milc import driver, fields
 from repro.core import BatchedField, Field, SOA, TargetConfig, tune
 
@@ -214,6 +215,7 @@ def gate_serving(metrics):
 
 
 def main(argv=None):
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny lattice (CI-sized run)")

@@ -9,9 +9,11 @@ is memory-bound on every processor, and dramatically more so on TPU.
 from __future__ import annotations
 
 from .common import PROCESSORS, ridge_point
+from repro.launch.compile_cache import setup_compile_cache
 
 
 def main(print_csv: bool = True):
+    setup_compile_cache()
     rows = []
     for name, (peak, bw) in PROCESSORS.items():
         rp = ridge_point(name)

@@ -13,12 +13,14 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.core import TargetConfig
 from repro.apps.ludwig import LudwigConfig, init_state, step
 from repro.apps.ludwig.driver import diagnostics
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--lattice", type=int, nargs=3, default=[16, 16, 16])
@@ -43,8 +45,11 @@ def main():
                   f"{float(d['free_energy']):+.6e}  {mom:.2e}")
     dt = time.perf_counter() - t0
     nsites = int(np.prod(cfg.lattice))
+    dev = jax.devices()[0]
     print(f"\n{args.steps} steps, {dt/args.steps*1e3:.1f} ms/step "
-          f"({nsites*args.steps/dt/1e6:.1f} Msite-updates/s on CPU)")
+          f"({nsites*args.steps/dt/1e6:.1f} Msite-updates/s on "
+          f"{dev.platform} {dev.device_kind}; a smoke timing, diagnostics "
+          f"included)")
     d = diagnostics(state, cfg)
     assert abs(float(d["mass"]) - float(d0["mass"])) < 1e-2 * float(d0["mass"])
     print("mass conserved; free energy relaxed "

@@ -7,11 +7,13 @@
 import argparse
 import time
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.apps.milc import MilcConfig, init_problem, solve
 from repro.apps.milc.driver import residual_check
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--lattice", type=int, nargs=4, default=[8, 8, 8, 8])
     ap.add_argument("--kappa", type=float, default=0.12)

@@ -12,6 +12,7 @@ import argparse
 
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.core import AOS, SOA, Field, LaunchGraph, TargetConfig, aosoa, kernel, launch, target_sum, telemetry
 
 
@@ -116,6 +117,7 @@ def fused_stencil_reduction_demo(lattice=(8, 8, 8), engine="pallas"):
 
 
 def main(argv=None):
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="enable telemetry and write a Chrome trace "

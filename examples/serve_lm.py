@@ -12,12 +12,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.configs import ARCH_IDS, get_arch
 from repro.models import init_params
 from repro.train.serve_step import build_serve_step, generate
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=ARCH_IDS)
     ap.add_argument("--batch", type=int, default=4)

@@ -14,6 +14,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.configs import ARCH_IDS, get_arch
 from repro.models import init_params
 from repro.train.data import DataConfig, TokenStream
@@ -23,6 +24,7 @@ from repro.train.train_step import TrainConfig, build_train_step, init_ef_state
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=ARCH_IDS)
     ap.add_argument("--steps", type=int, default=50)

@@ -413,15 +413,23 @@ def make_sharded_step(cfg: LudwigConfig, domain: Domain, halo: str = "pre"):
     spec = domain.spec()
     WQ = 2  # q halo: grad/lap (1) + stress divergence (1)
     dec = domain.decomposed
+    dec_dims = {d for d, _, _ in dec}
 
-    def pad(x, w):
-        # wrap-pad ALL site dims: for non-decomposed dims the wrap IS the
-        # (local-)periodic halo; for decomposed dims exchange overwrites it.
-        pads = [(0, 0)] + [(w, w)] * 3
+    def pad(x, w, every_dim=False):
+        # wrap-pad the decomposed dims (exchange overwrites them with
+        # neighbour data).  The jnp stencils roll periodically, so a
+        # non-decomposed dim needs no halo — and leaving it unpadded keeps
+        # the site-local launches on whole (8, 128) tiles.  The fused LB
+        # launch's halo="pre" contract pads every dim (``every_dim``): for
+        # a non-decomposed dim the wrap IS the periodic halo.
+        pads = [(0, 0)] + [(w, w) if (every_dim or d in dec_dims) else (0, 0)
+                           for d in range(1, x.ndim)]
         return jnp.pad(x, pads, mode="wrap")
 
     def crop(x, w):
-        idx = [slice(None)] + [slice(w, s - w) for s in x.shape[1:]]
+        idx = [slice(None)] + [slice(w, s - w) if d in dec_dims
+                               else slice(None)
+                               for d, s in enumerate(x.shape[1:], 1)]
         return x[tuple(idx)]
 
     def exchange_w(x, w):
@@ -462,16 +470,16 @@ def make_sharded_step(cfg: LudwigConfig, domain: Domain, halo: str = "pre"):
         # overlap scheduler (interior sub-launch independent of the
         # exchange, boundary slabs after it — core.overlap).
         if halo == "pre":
-            d_h = exchange_w(pad(dist_nd, 1), 1)
-            f_h = exchange_w(pad(force_nd, 1), 1)
+            d_h = exchange_w(pad(dist_nd, 1, every_dim=True), 1)
+            f_h = exchange_w(pad(force_nd, 1, every_dim=True), 1)
             lb = lb_pre_step(
                 {"dist": mk("dist", d_h), "force": mk("force", f_h)})
         else:
             from repro.core import overlap_launch
             lb = overlap_launch(
                 lb_step_graph(cfg),
-                {"dist": mk("dist", pad(dist_nd, 1)),
-                 "force": mk("force", pad(force_nd, 1))},
+                {"dist": mk("dist", pad(dist_nd, 1, every_dim=True)),
+                 "force": mk("force", pad(force_nd, 1, every_dim=True))},
                 decomposed=dec, config=tgt, outputs=("dist2", "u"),
                 halo=halo,
             )

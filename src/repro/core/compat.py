@@ -1,13 +1,10 @@
-"""Version gates for JAX APIs that moved between releases.
+"""The sharded entry points' one view of the JAX mesh API.
 
 The sharded wrappers (core.halo callers, train sharding, the distributed
-tests) were written against the modern surface: ``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``.  Older
-jax (e.g. 0.4.x, where shard_map still lives in ``jax.experimental``) ships
-none of those, so every sharded entry point routes through this module
-instead of feature-detecting inline.  Single-shard code paths never import
-these symbols at call time, preserving the paper's portability discipline:
-the same application source runs on whatever runtime is underneath.
+tests) route through here, so a change of the runtime's sharding surface is
+met in one place.  Single-shard code paths never import these symbols at
+call time, preserving the paper's portability discipline: the same
+application source runs on whatever runtime is underneath.
 """
 
 from __future__ import annotations
@@ -15,35 +12,29 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "make_mesh", "HAS_NATIVE_SHARD_MAP"]
+__all__ = ["shard_map", "make_mesh"]
 
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
 
-if HAS_NATIVE_SHARD_MAP:
-    shard_map = jax.shard_map
-else:  # jax < 0.5: the experimental home, same keyword surface
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
+def shard_map(f, **kwargs):
+    """``jax.shard_map`` with the varying-manual-axes check off by default.
 
-    def shard_map(f, **kwargs):
-        # the experimental replication checker has no rule for while_loop
-        # (the CG solver's carrier); the native one does — disable it rather
-        # than forbid control flow under old runtimes
-        kwargs.setdefault("check_rep", False)
-        return _experimental_shard_map(f, **kwargs)
+    The check needs every primitive in the body to state which mesh axes
+    its outputs vary over; a ``pallas_call`` builds its outputs from plain
+    ``ShapeDtypeStruct``s that carry none, so with the check on every fused
+    launch inside a shard fails to trace.  The drivers' bodies are written
+    per shard (explicit halo exchange, explicit psums), which is exactly
+    what the unchecked mode assumes."""
+    kwargs.setdefault("check_vma", False)
+    return jax.shard_map(f, **kwargs)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis_types when the runtime has them
-    (explicit-sharding jax), plain otherwise (0.4.x: every mesh axis is
-    implicitly auto, which is the behaviour the sharded wrappers assume)."""
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with every axis Auto — the implicit sharding the
+    sharded wrappers assume."""
     axis_names = tuple(axis_names)
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(axis_shapes, axis_names)
     return jax.make_mesh(
-        axis_shapes, axis_names,
+        tuple(axis_shapes), axis_names,
         axis_types=(AxisType.Auto,) * len(axis_names),
     )

@@ -372,6 +372,20 @@ def _stage_in_cast(storage_dt, compute_dt, in_dtypes):
     return cast
 
 
+def _fold_window(op: str, a: jax.Array) -> jax.Array:
+    """Fold an ``(ncomp, *window)`` value over its site axes to a
+    ``(ncomp, 1)`` per-block partial: the leading site axes elementwise,
+    then the sublane axis, then the lane axis with keepdims — the order
+    Mosaic lowers (a rank-1 result, or one reduce over both tiled axes,
+    aborts its layout pass)."""
+    fold = _RED_FOLD[op]
+    if a.ndim > 3:
+        a = fold(a, axis=tuple(range(1, a.ndim - 2)))
+    if a.ndim == 3:
+        a = fold(a, axis=1)
+    return fold(a, axis=1, keepdims=True)
+
+
 def _crop_ring(arr: jax.Array, r_from: int, r_to: int) -> jax.Array:
     """Shrink an (ncomp, *window) value from valid ring r_from to r_to."""
     if r_from == r_to:
@@ -1147,8 +1161,10 @@ class LaunchGraph:
         for st in self._stages:
             if st.kind == "reduce":
                 ((_, vname),) = st.ins
+                # keepdims: a (ncomp, 1) partial, never a rank-1 value
+                # (which Mosaic cannot lay out)
                 partials[st.outs[0][1]] = _RED_FOLD[st.op](
-                    values[vname], axis=1)
+                    values[vname], axis=1, keepdims=True)
                 continue
             chunks = {arg: values[v] for arg, v in st.ins}
             outs = st.kernel.body(chunks, **dict(st.params))
@@ -1170,18 +1186,19 @@ class LaunchGraph:
                Dict[str, jax.Array]]:
         """Stencil composed body: values are (array, ring) pairs where array
         has shape (ncomp, *window) and ring counts valid halo sites around
-        the window's interior.  Site-local stages run (flattened) over the
-        whole window — recomputing on halo sites so later stencil stages can
-        gather from intermediates; stencil stages shrink the ring by their
-        width; reductions fold the ring-0 interior into per-block partials."""
+        the window's interior.  Site-local stages run on the nd window itself
+        (bodies are elementwise over the trailing site axes, so no reshape
+        merges lattice dims inside a kernel — Mosaic cannot relayout those)
+        — recomputing on halo sites so later stencil stages can gather from
+        intermediates; stencil stages shrink the ring by their width;
+        reductions fold the ring-0 interior into per-block partials."""
         partials: Dict[str, jax.Array] = {}
         for st in self._stages:
             if st.kind == "reduce":
                 ((_, vname),) = st.ins
                 arr, r = values[vname]
-                a0 = _crop_ring(arr, r, 0)
-                partials[st.outs[0][1]] = _RED_FOLD[st.op](
-                    a0.reshape(a0.shape[0], -1), axis=1)
+                partials[st.outs[0][1]] = _fold_window(
+                    st.op, _crop_ring(arr, r, 0))
                 continue
 
             stage_ins = [(arg, values[v]) for arg, v in st.ins]
@@ -1247,16 +1264,14 @@ class LaunchGraph:
                     values[vname] = (arr, r_out)
                 continue
 
-            # site-local: crop all inputs to the common ring, flatten, run
-            win_shape = None
+            # site-local: crop all inputs to the common ring, run on the
+            # (ncomp, *window) values directly
             chunks = {}
             for arg, (arr, r) in stage_ins:
-                if r is None:
-                    chunks[arg] = arr  # (1, 1) broadcasts against (ncomp, L)
+                if r is None:  # scalar: broadcast over the nd window
+                    chunks[arg] = arr.reshape((1,) * (1 + site_ndim))
                 else:
-                    w = _crop_ring(arr, r, r_in)
-                    win_shape = w.shape[1:]
-                    chunks[arg] = w.reshape(w.shape[0], -1)
+                    chunks[arg] = _crop_ring(arr, r, r_in)
             outs = st.kernel.body(chunks, **dict(st.params))
             for body_key, vname, ncomp, _ in st.outs:
                 arr = outs[body_key]
@@ -1265,7 +1280,7 @@ class LaunchGraph:
                         f"stage {st.kernel.name!r} output {body_key!r} has "
                         f"ncomp {arr.shape[0]}, declared {ncomp}"
                     )
-                values[vname] = (arr.reshape((ncomp,) + win_shape), r_in)
+                values[vname] = (arr, r_in)
         return values, partials
 
     # -- lowering: flat site-block grid (site-local graphs) ----------------------
@@ -1320,7 +1335,7 @@ class LaunchGraph:
                 src = values[red_spec[o].source].astype(dt)
                 return kahan_fold(src, axis=1) if comp \
                     else jnp.sum(src, axis=1)
-            return partials[o].astype(out_info[o][1])
+            return partials[o][:, 0].astype(out_info[o][1])
 
         if engine == "jnp":
 
@@ -1429,7 +1444,7 @@ class LaunchGraph:
                 r[...] = blk[None] if batch else blk
             for o, r in zip(red_outputs, acc_refs):
                 spec = red_spec[o]
-                part = partials[o][:, None].astype(out_info[o][1])
+                part = partials[o].astype(out_info[o][1])
                 while part.ndim < len(r.shape):
                     part = part[None]
                 # compensated sums carry (sum, compensation) columns
@@ -1533,7 +1548,7 @@ class LaunchGraph:
                 a0 = a0.reshape(a0.shape[0], -1).astype(dt)
                 return kahan_fold(a0, axis=1) if comp \
                     else jnp.sum(a0, axis=1)
-            return partials[o].astype(out_info[o][1])
+            return partials[o][:, 0].astype(out_info[o][1])
 
         if engine == "jnp":
 
@@ -1712,7 +1727,7 @@ class LaunchGraph:
                 r[...] = a0[None] if batch else a0
             for o, r in zip(red_outputs, acc_refs):
                 spec = red_spec[o]
-                part = partials[o][:, None].astype(out_info[o][1])
+                part = partials[o].astype(out_info[o][1])
                 while part.ndim < len(r.shape):
                     part = part[None]
                 comb = _kahan_combine if o in red_widths else spec.combine
@@ -1741,9 +1756,11 @@ class LaunchGraph:
             for n, (ncomp, lay), hlat, ring, nat, bat, r in zip(
                     ordered_ins, in_meta, hlats, in_rings, native_in,
                     in_batched, in_refs):
-                # full halo'd stage (VMEM); batched refs carry a leading
-                # length-1 batch-row axis
-                arr = r[...][0] if (batch and bat) else r[...]
+                # the halo'd window is read straight from the VMEM-staged
+                # ref with pl.ds (Mosaic has no dynamic_slice on loaded
+                # values); batched refs carry a leading length-1 batch-row
+                # axis
+                lead = (0,) if (batch and bat) else ()
                 rows = bx + 2 * ring
                 tstarts, tsizes = tile_tail(ys, zs, ring, hlat)
                 if nat:
@@ -1759,11 +1776,8 @@ class LaunchGraph:
                     # dividing by/bz (the per-tile block_view_ok
                     # discipline)
                     row_blocks = int(math.prod(hlat[1:])) // lay.sal
-                    tile = jax.lax.dynamic_slice(
-                        arr,
-                        (xs * row_blocks, 0, 0),
-                        (rows * row_blocks, ncomp, lay.sal),
-                    )
+                    tile = r[lead + (pl.ds(xs * row_blocks,
+                                           rows * row_blocks),)]
                     window = tile.transpose(1, 0, 2).reshape(
                         (ncomp, rows) + hlat[1:])
                     if tiled:
@@ -1771,35 +1785,56 @@ class LaunchGraph:
                             window, (0, 0, *tstarts),
                             (ncomp, rows, *tsizes))
                 else:
-                    window = jax.lax.dynamic_slice(
-                        arr,
-                        (0, xs, *tstarts),
-                        (ncomp, rows, *tsizes),
-                    )
+                    window = r[lead + (slice(None), pl.ds(xs, rows)) + tuple(
+                        pl.ds(s, z) for s, z in zip(tstarts, tsizes))]
                 values[n] = (window, ring)
             finish_tile(values, sc_refs, out_refs, acc_refs)
 
-        # Double-buffered DMA pipeline (tiled pallas on a real TPU only):
-        # inputs stay in HBM (memory_space=ANY) and each program DMAs its
-        # halo'd tile window into one of two VMEM scratch slots, starting
-        # the copy for tile t+1 before waiting on tile t's — grid axes are
+        # Double-buffered DMA pipeline (compiled launches): inputs stay in
+        # HBM (memory_space=ANY) and each program DMAs its halo'd x-slab or
+        # tile window into one of two VMEM scratch slots, starting the copy
+        # for tile t+1 before waiting on tile t's — grid axes are
         # sequential on TPU, so tile t+1's transfer overlaps tile t's
-        # compute.  Gated off under interpret (no async-copy semantics),
-        # rsplit/batch (extra grid axes ahead of the tile axes would need
-        # their own linearization), and native AoSoA inputs (block-rebased
-        # windows are staged whole).  Everything downstream of the window
-        # (finish_tile) is shared with the fallback, so the pipeline is a
-        # pure data-movement change.
-        use_dma = (
-            tiled and not interpret and rsplit == 1 and not batch
-            and not any(native_in)
-            and jax.default_backend() == "tpu"
-        )
-        n_lin = nslabs * nty * ntz
-        win_shapes = []
-        for (ncomp, lay), hlat, ring in zip(in_meta, hlats, in_rings):
+        # compute.  A batched launch linearizes its batch axis ahead of the
+        # tiles (the prefetch chain runs on across batch rows).  Every
+        # compiled launch takes this path — whole staging would put the
+        # halo'd lattice in VMEM — except native AoSoA inputs
+        # (block-rebased windows are staged whole); interpret mode stages
+        # whole (the plain interpreter has no async-copy semantics).
+        # Everything downstream of the window (finish_tile) is shared with
+        # the staged kernel, so the pipeline is a pure data-movement change.
+        use_dma = not interpret and not any(native_in)
+        n_tiles = nslabs * nty * ntz
+        n_lin = max(batch, 1) * n_tiles
+        # window dtypes: staged float inputs were cast to the effective
+        # compute dtype, so the DMA window slots match it
+        dma_dts = tuple(jnp.dtype(dt) for dt in
+                        (in_dtypes or (jnp.float32,) * nin))
+        if cast_in is not None:
+            cdt = jnp.dtype(compute_dt or storage_dt)
+            dma_dts = tuple(cdt if jnp.issubdtype(dt, jnp.floating) else dt
+                            for dt in dma_dts)
+        # Mosaic slices HBM only in whole (sublane, 128-lane) tiles of the
+        # two minor axes, so the DMA window rounds those extents up to a
+        # tile, the staged input is zero-padded to keep every window in
+        # bounds, and the kernel slices the logical window back out of the
+        # slot (a static, offset-0 value slice).
+        minor = tuple(range(max(site_ndim - 2, 0), site_ndim))
+        win_shapes, dma_wins, stage_pads = [], [], []
+        for (ncomp, lay), hlat, ring, dt in zip(in_meta, hlats, in_rings,
+                                                dma_dts):
             _, tsz = tile_tail(0, 0, ring, hlat)
-            win_shapes.append((ncomp, bx + 2 * ring) + tuple(tsz))
+            win = (ncomp, bx + 2 * ring) + tuple(tsz)
+            win_shapes.append(win)
+            dwin, pads = list(win), [0] * site_ndim
+            for d in minor:
+                m = 128 if d == site_ndim - 1 else 8 * max(1, 4 // dt.itemsize)
+                dwin[d + 1] = -(-win[d + 1] // m) * m
+                step = {1: by, 2: bz}.get(d, 0)
+                last = (lattice[d] // step - 1) * step if step else 0
+                pads[d] = max(0, last + dwin[d + 1] - hlat[d])
+            dma_wins.append(tuple(dwin))
+            stage_pads.append(pads)
 
         def dma_kernel(*refs):
             from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
@@ -1812,32 +1847,41 @@ class LaunchGraph:
             bufs = refs[nin + nsc + nfield + nred :
                         nin + nsc + nfield + nred + nin]
             sems = refs[nin + nsc + nfield + nred + nin :]
-            tax = 1
+            if rsplit > 1:  # x-slab index rebased from the split grid axes
+                i = pl.program_id(axis0) * per + pl.program_id(axis0 + 1)
+                tax = axis0 + 2
+            else:
+                i = pl.program_id(axis0)
+                tax = axis0 + 1
             jt = 0
             if by:
                 jt = pl.program_id(tax)
                 tax += 1
             kt = pl.program_id(tax) if bz else 0
-            i = pl.program_id(0)
             # linear tile index: the grid iterates the z-tile axis fastest
+            # (a split grid visits slabs in the unsplit order)
             t = (i * nty + jt) * ntz + kt
+            if batch:
+                t = pl.program_id(0) * n_tiles + t
 
             def copy(tl, slot, idx):
                 """Async-copy descriptor for input idx's halo'd window of
                 linear tile tl into scratch slot ``slot``."""
-                ii = tl // (nty * ntz)
+                bb = tl // n_tiles
+                ii = (tl // (nty * ntz)) % nslabs
                 jj = (tl // ntz) % nty
                 kk = tl % ntz
                 ring = in_rings[idx]
-                hlat = hlats[idx]
-                src = [slice(None), pl.ds(ii * bx, bx + 2 * ring)]
+                src = [bb] if (batch and in_batched[idx]) else []
+                src += [slice(None), pl.ds(ii * bx, bx + 2 * ring)]
                 for d in range(1, site_ndim):
+                    ext = dma_wins[idx][d + 1]
                     if d == 1 and by:
-                        src.append(pl.ds(jj * by, by + 2 * ring))
+                        src.append(pl.ds(jj * by, ext))
                     elif d == 2 and bz:
-                        src.append(pl.ds(kk * bz, bz + 2 * ring))
+                        src.append(pl.ds(kk * bz, ext))
                     else:
-                        src.append(slice(0, hlat[d]))
+                        src.append(pl.ds(0, ext))
                 return pltpu.make_async_copy(
                     in_refs[idx].at[tuple(src)],
                     bufs[idx].at[slot],
@@ -1859,7 +1903,10 @@ class LaunchGraph:
             values = {}
             for ix, (n, ring) in enumerate(zip(ordered_ins, in_rings)):
                 copy(t, slot, ix).wait()
-                values[n] = (bufs[ix][slot], ring)
+                win = bufs[ix][slot]
+                if win.shape != win_shapes[ix]:  # drop the tile round-up
+                    win = win[tuple(slice(0, e) for e in win_shapes[ix])]
+                values[n] = (win, ring)
             finish_tile(values, sc_refs, out_refs, acc_refs)
 
         def stage_in(n, meta, lat, ring, nat, d):
@@ -1887,30 +1934,30 @@ class LaunchGraph:
                     staged.append(stage_in(n, meta, lat, ring, nat, d))
             kernel = fused_kernel
             call_kw = dict(in_specs=in_specs)
-            if use_dma:
+            if not interpret:
                 from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
+                # a scoped-VMEM limit that agrees with the planner's
+                # device budget (core.plan.vmem_limit_bytes)
+                call_kw["compiler_params"] = pltpu.CompilerParams(
+                    vmem_limit_bytes=plan_mod.vmem_limit_bytes())
+            if use_dma:
                 kernel = dma_kernel
                 # inputs stay in HBM; two window slots + one DMA
                 # semaphore pair of scratch per input
                 call_kw["in_specs"] = (
-                    [pl.BlockSpec(memory_space=pltpu.ANY)
+                    [pl.BlockSpec(memory_space=pl.ANY)
                      for _ in range(nin)] + list(in_specs[nin:])
                 )
-                dts = in_dtypes or tuple(
-                    jnp.float32 for _ in range(nin))
-                if cast_in is not None:
-                    # staged float inputs were cast to the effective
-                    # compute dtype, so the DMA window slots match it
-                    cdt = compute_dt or storage_dt
-                    dts = tuple(
-                        cdt if jnp.issubdtype(jnp.dtype(dt), jnp.floating)
-                        else dt for dt in dts)
-                call_kw["scratch_shapes"] = (
-                    [pltpu.VMEM((2,) + w, jnp.dtype(dt))
-                     for w, dt in zip(win_shapes, dts)]
-                    + [pltpu.SemaphoreType.DMA((2,)) for _ in range(nin)]
-                )
+                for ix, pads in enumerate(stage_pads):
+                    if any(pads):
+                        lead = staged[ix].ndim - site_ndim
+                        staged[ix] = jnp.pad(staged[ix], [(0, 0)] * lead + [
+                            (0, p) for p in pads])
+                call_kw["scratch_shapes"] = [
+                    pltpu.VMEM((2,) + w, dt)
+                    for w, dt in zip(dma_wins, dma_dts)] + [
+                    pltpu.SemaphoreType.DMA((2,)) for _ in range(nin)]
             call = pl.pallas_call(
                 kernel,
                 grid=grid,

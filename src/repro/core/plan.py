@@ -82,6 +82,8 @@ __all__ = [
     "tile_extents",
     "estimate_vmem_bytes",
     "resolved_vmem_bytes",
+    "tpu_vmem_capacity",
+    "vmem_limit_bytes",
 ]
 
 log = logging.getLogger(__name__)
@@ -90,6 +92,18 @@ log = logging.getLogger(__name__)
 # resolved_vmem_bytes); an unset/empty value means "unbounded", which keeps
 # every default plan bit-identical to the pre-budget heuristics
 VMEM_ENV = "TARGETDP_VMEM_BYTES"
+
+# Per-core VMEM capacity by jax ``device_kind`` — the one table the compiled
+# (non-interpret) pallas path sizes itself from.  Source: Google Cloud TPU
+# documentation ("TPU v5e": 128 MiB of VMEM per TensorCore).  A compiled
+# stencil launch on a kind missing here is an error, not a guess.
+TPU_VMEM_BYTES = {"TPU v5 lite": 128 * 2**20}
+# Of that capacity, the planner budgets this share for the staged inputs
+# and output blocks it models (estimate_vmem_bytes, TPU-padded), and the
+# kernels' scoped-VMEM limit (vmem_limit_bytes) grants this share — the
+# rest of the limit holds the values Mosaic keeps live inside the body.
+_VMEM_PLAN_SHARE = 2    # budget = capacity / 2
+_VMEM_LIMIT_SHARE = 8   # limit  = capacity * 7 / 8
 
 VIEW_BLOCK = "block"
 VIEW_STAGED_ND = "staged-nd"
@@ -333,11 +347,39 @@ def tile_extents(
     return tuple(ext)
 
 
+def _device_kind() -> str:
+    import jax  # noqa: PLC0415  (plan construction stays jax-free)
+
+    return jax.devices()[0].device_kind
+
+
+def tpu_vmem_capacity() -> int:
+    """Per-core VMEM bytes of the device the compiled kernels target, from
+    :data:`TPU_VMEM_BYTES`; raises for a device kind not in the table."""
+    kind = _device_kind()
+    try:
+        return TPU_VMEM_BYTES[kind]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM capacity known for device kind {kind!r}; add it to "
+            f"core.plan.TPU_VMEM_BYTES (have {sorted(TPU_VMEM_BYTES)})"
+        ) from None
+
+
+def vmem_limit_bytes() -> int:
+    """The scoped-VMEM limit compiled stencil kernels are given: the plan
+    budget (capacity / 2) plus room for the values Mosaic keeps live."""
+    cap = tpu_vmem_capacity()
+    return cap - cap // _VMEM_LIMIT_SHARE
+
+
 def resolved_vmem_bytes(config) -> Optional[int]:
     """The per-program VMEM byte budget in effect: an explicit
-    ``TargetConfig.vmem_bytes`` wins, else ``$TARGETDP_VMEM_BYTES``, else
-    None (unbounded — the pre-budget behavior, so default plans stay
-    bit-identical unless a budget is actually configured)."""
+    ``TargetConfig.vmem_bytes`` wins, else ``$TARGETDP_VMEM_BYTES``.  With
+    neither set, a compiled (non-interpret) pallas config gets half of the
+    device's VMEM (:func:`tpu_vmem_capacity`), so default stencil plans
+    tile at sizes whose whole halo'd inputs would not fit; interpret and jnp
+    configs stay unbounded (None — the pre-budget behavior)."""
     vb = getattr(config, "vmem_bytes", None)
     if vb is not None:
         return int(vb) or None
@@ -347,7 +389,24 @@ def resolved_vmem_bytes(config) -> Optional[int]:
             return int(env) or None
         except ValueError:
             log.warning("ignoring non-integer $%s=%r", VMEM_ENV, env)
+    if (getattr(config, "engine", "jnp") == "pallas"
+            and not config.resolved_interpret()):
+        return tpu_vmem_capacity() // _VMEM_PLAN_SHARE
     return None
+
+
+def _buffer_bytes(ncomp: int, extents: Sequence[int], isz: int,
+                  tpu: bool) -> int:
+    """Bytes of one ``(ncomp, *extents)`` VMEM buffer.  ``tpu`` pads the two
+    minor axes to the (sublane, 128-lane) tile Mosaic lays them out in —
+    a 130-wide halo'd z axis occupies 256 lanes."""
+    ext = [int(e) for e in extents]
+    if tpu and ext:
+        ext[-1] = -(-ext[-1] // 128) * 128
+        if len(ext) > 1:
+            sub = 8 * 4 // isz if isz < 4 else 8
+            ext[-2] = -(-ext[-2] // sub) * sub
+    return ncomp * int(math.prod(ext)) * isz
 
 
 def estimate_vmem_bytes(
@@ -356,6 +415,7 @@ def estimate_vmem_bytes(
     lattice: Sequence[int],
     in_views: Sequence[Tuple[int, int, int]],
     out_views: Sequence[Tuple[int, int]] = (),
+    tpu: bool = False,
 ) -> int:
     """Model the per-program VMEM footprint of a stencil launch in bytes.
 
@@ -371,7 +431,12 @@ def estimate_vmem_bytes(
     A plan carrying a storage :class:`DtypePolicy` is priced at the
     *storage* itemsize (fields are staged and written in the storage
     dtype), so bf16 candidates are budgeted against their real footprint
-    rather than the caller's fp32 one."""
+    rather than the caller's fp32 one.
+
+    ``tpu`` prices the buffers as a compiled TPU kernel lays them out: the
+    two minor axes padded to the (8, 128) tile, inputs as the two DMA
+    window slots of an x-slab (or tile) whether or not y/z are tiled, and
+    output blocks double-buffered by the Pallas pipeline."""
     bx = plan.bx or lattice[0]
     tiled = bool(plan.by or plan.bz)
     if plan.dtypes is not None and plan.dtypes.storage:
@@ -379,22 +444,24 @@ def estimate_vmem_bytes(
                     for nc, ring, isz in in_views]
         out_views = [(nc, plan.dtypes.storage_itemsize(isz))
                      for nc, isz in out_views]
+    staged = 2 if tpu else 1
     total = 0
     for ncomp, ring, isz in in_views:
-        if tiled:
+        # a compiled kernel always streams DMA windows (x-slab, or tile)
+        if tiled or tpu:
             win = [bx + 2 * ring]
             if len(lattice) > 1:
                 win.append((plan.by or lattice[1]) + 2 * ring)
             if len(lattice) > 2:
                 win.append((plan.bz or lattice[2]) + 2 * ring)
             win.extend(s + 2 * ring for s in lattice[3:])
-            total += 2 * ncomp * int(math.prod(win)) * isz
+            total += 2 * _buffer_bytes(ncomp, win, isz, tpu)
         else:
-            total += ncomp * int(
-                math.prod(s + 2 * ring for s in lattice)) * isz
-    tile_sites = int(math.prod(tile_extents(lattice, bx, plan.by, plan.bz)))
+            total += staged * _buffer_bytes(
+                ncomp, [s + 2 * ring for s in lattice], isz, tpu)
+    tile = tile_extents(lattice, bx, plan.by, plan.bz)
     for ncomp, isz in out_views:
-        total += ncomp * tile_sites * isz
+        total += staged * _buffer_bytes(ncomp, tile, isz, tpu)
     return total
 
 
@@ -406,23 +473,41 @@ def choose_tiles(
     out_views: Sequence[Tuple[int, int]],
     vmem_bytes: int,
     dtypes: Optional["DtypePolicy"] = None,
+    tpu: bool = False,
 ) -> Tuple[int, int]:
     """Pick the largest (by, bz) tile whose estimated footprint fits the
     byte budget, preferring to keep the minor (z) axis whole — tile windows
     stay contiguous along the fast axis, which is what the DMA engine
     wants.  Returns (0, 0) when untiled whole-staging already fits, and the
     finest legal tile (best effort) when even it exceeds the budget.
-    ``dtypes`` prices the probe at the policy's storage itemsize."""
+    ``dtypes`` prices the probe at the policy's storage itemsize, ``tpu``
+    at the compiled kernel's padded layout (:func:`estimate_vmem_bytes`)."""
 
     def fp(by, bz):
         probe = LoweringPlan("pallas", bx=bx, by=by, bz=bz, dtypes=dtypes)
         return estimate_vmem_bytes(
-            probe, lattice=lattice, in_views=in_views, out_views=out_views)
+            probe, lattice=lattice, in_views=in_views, out_views=out_views,
+            tpu=tpu)
 
     if fp(0, 0) <= vmem_bytes:
         return (0, 0)
-    bys = [d for d in divisors(lattice[1])] if len(lattice) > 1 else [0]
-    bzs = [d for d in divisors(lattice[2])] if len(lattice) > 2 else [0]
+    isz = min((dtypes.storage_itemsize(i) if dtypes and dtypes.storage
+               else i for _, i in out_views), default=4)
+    sublane = 8 * max(1, 4 // isz)
+
+    def legal(d, ext):
+        # a compiled kernel cuts the sublane axis only in whole sublane
+        # tiles (its halo'd DMA window is rounded up to one) and never the
+        # 128-lane axis: a halo'd lane window cannot be tile-aligned
+        minor = len(lattice) - d
+        if not tpu or ext == lattice[d] or minor > 2:
+            return True
+        return minor == 2 and ext % sublane == 0
+
+    bys = ([d for d in divisors(lattice[1]) if legal(1, d)]
+           if len(lattice) > 1 else [0])
+    bzs = ([d for d in divisors(lattice[2]) if legal(2, d)]
+           if len(lattice) > 2 else [0])
     pairs = [(by, bz) for by in bys for bz in bzs]
     # largest tile first; prefer whole-z (bz == lattice[2]) on ties
     pairs.sort(key=lambda p: ((p[0] or 1) * (p[1] or 1), p[1] or 1),
@@ -434,8 +519,8 @@ def choose_tiles(
             continue  # the untiled probe already failed
         if fp(by_eff, bz_eff) <= vmem_bytes:
             return (by_eff, bz_eff)
-    return (1 if len(lattice) > 1 and lattice[1] > 1 else 0,
-            1 if len(lattice) > 2 and lattice[2] > 1 else 0)
+    return (bys[0] if len(lattice) > 1 and bys[0] < lattice[1] else 0,
+            bzs[0] if len(lattice) > 2 and bzs[0] < lattice[2] else 0)
 
 
 def resolve_vvl(config, nsites: int, layouts: Sequence[Layout]) -> int:
@@ -745,7 +830,8 @@ def default_plan(
         if budget and vmem_views:
             by, bz = choose_tiles(
                 lattice, bx, in_views=vmem_views[0],
-                out_views=vmem_views[1], vmem_bytes=budget)
+                out_views=vmem_views[1], vmem_bytes=budget,
+                tpu=not interpret)
         return LoweringPlan("pallas", vvl=0, bx=bx, interpret=interpret,
                             halo=halo, view=VIEW_STAGED_ND, by=by, bz=bz)
     vvl = resolve_vvl(config, nsites, layouts)
@@ -959,7 +1045,8 @@ def candidate_plans(
                 return False
             fp = estimate_vmem_bytes(c, lattice=lattice,
                                      in_views=vmem_views[0],
-                                     out_views=vmem_views[1])
+                                     out_views=vmem_views[1],
+                                     tpu=not c.interpret)
             if fp <= vmem_budget:
                 return False
             log.info(
@@ -981,7 +1068,10 @@ def candidate_plans(
             devices = jax.device_count()
         with_overlap = halo == "pre" and devices > 1 and not batch
         if block_view is None:
-            block_view = any(lay.kind is LayoutKind.AOSOA for lay in layouts)
+            # Mosaic refuses the native block view's in-kernel relayout of
+            # AoSoA rows into the nd window, so compiled plans get no twin
+            block_view = default.interpret and any(
+                lay.kind is LayoutKind.AOSOA for lay in layouts)
         # split-reduction twins come off the default geometry (or the
         # narrowest swept slab when the default lowers the whole extent as
         # one program); computed first so the bx sweep only cedes budget

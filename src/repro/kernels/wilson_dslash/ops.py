@@ -36,10 +36,9 @@ def dslash_stencil_body(v, gather):
          for mu in range(4)],
         axis=0,
     )                                                           # (72, *win)
-    win = u_fwd.shape[1:]
-    flat = lambda a: a.reshape(a.shape[0], -1)
-    out = ref.dslash_site_chunk(flat(u_fwd), flat(u_bwd), flat(nbrs))
-    return {"d": out.reshape((ref.SPINOR_NCOMP,) + win)}
+    # the site chunk is elementwise over the trailing axes: it runs on the
+    # nd window as-is (no in-kernel reshape across lattice dims)
+    return {"d": ref.dslash_site_chunk(u_fwd, u_bwd, nbrs)}
 
 
 def dslash(psi: Field, u: Field, *, config: TargetConfig) -> Field:

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.core import BatchedField, Field, TargetConfig, telemetry
 
 
@@ -278,6 +279,7 @@ def _main_solve(args):
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=None, default=None)
     ap.add_argument("--batch", type=int, default=8)

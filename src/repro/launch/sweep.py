@@ -34,7 +34,9 @@ def run_cell(arch, shape, mesh, out, timeout=1800):
         # multi-pod pass is the shardability proof; the roofline table is
         # single-pod only (spec), so skip the L1/L2 cost probes here.
         cmd.append("--no-exact-loops")
-    env = dict(os.environ)
+    # the dry run compiles on fake host devices: a child on the CPU never
+    # competes for a chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     t0 = time.time()
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,

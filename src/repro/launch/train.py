@@ -10,19 +10,9 @@ compute/collective overlap (latency-hiding scheduler) are set here.
       --steps 100 --smoke-arch
 """
 
-import os
-
-# collective/compute overlap: enable XLA's latency-hiding scheduler and
-# async collectives (the TPU defaults; stated explicitly because they are
-# part of the §Perf story)
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-)
-
 import argparse
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +26,28 @@ from repro.train.optimizer import OptConfig, init_opt, opt_kind_for
 from repro.train.sharding import param_specs, set_rules
 from repro.train.train_step import TrainConfig, build_train_step
 
+# collective/compute overlap: XLA's latency-hiding scheduler and async
+# collectives (the TPU defaults; stated explicitly because they are part of
+# the §Perf story)
+OVERLAP_FLAGS = (
+    "--xla_tpu_enable_async_collective_fusion=true",
+    "--xla_tpu_enable_latency_hiding_scheduler=true",
+)
+
+
+def append_libtpu_flags(flags, environ=os.environ) -> str:
+    """Add ``flags`` to ``$LIBTPU_INIT_ARGS`` before the TPU backend starts.
+    Whatever the variable already holds is kept — a machine may set flags
+    it needs there — and a flag it already names is not added again."""
+    have = environ.get("LIBTPU_INIT_ARGS", "").split()
+    named = {f.split("=", 1)[0] for f in have}
+    extra = [f for f in flags if f.split("=", 1)[0] not in named]
+    environ["LIBTPU_INIT_ARGS"] = " ".join(have + extra)
+    return environ["LIBTPU_INIT_ARGS"]
+
 
 def main():
+    append_libtpu_flags(OVERLAP_FLAGS)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--steps", type=int, default=100)

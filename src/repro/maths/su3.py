@@ -67,56 +67,52 @@ def cneg_i_mul(a: Pair) -> Pair:
 # SU(3) action on color vectors
 # ---------------------------------------------------------------------------
 
+def _mat_vec(m: jnp.ndarray, v: jnp.ndarray, adj: bool = False) -> jnp.ndarray:
+    """sum_b m[a, b] v[b] (m[b, a] when ``adj``) for a real 3x3 colour
+    matrix m (3,3,...) and colour vector v (3,...).  Unrolled over
+    Python-int colour indices, as lc.py unrolls its 3x3 algebra: a length-3
+    contraction is too small for the MXU, and elementwise multiply-adds are
+    what a Pallas TPU kernel can lower (Mosaic has no batched dot_general
+    over trailing site axes)."""
+    rows = []
+    for a in range(3):
+        acc = None
+        for b in range(3):
+            t = (m[b, a] if adj else m[a, b]) * v[b]
+            acc = t if acc is None else acc + t
+        rows.append(acc)
+    return jnp.stack(rows)
+
+
 def su3_mult_vec(u: Pair, v: Pair) -> Pair:
     """(U v): u = (3,3,...), v = (3,...) -> (3,...)."""
     ur, ui = u
     vr, vi = v
-    outr = jnp.einsum("ab...,b...->a...", ur, vr) - jnp.einsum(
-        "ab...,b...->a...", ui, vi
-    )
-    outi = jnp.einsum("ab...,b...->a...", ur, vi) + jnp.einsum(
-        "ab...,b...->a...", ui, vr
-    )
-    return outr, outi
+    return (_mat_vec(ur, vr) - _mat_vec(ui, vi),
+            _mat_vec(ur, vi) + _mat_vec(ui, vr))
 
 
 def su3_adj_mult_vec(u: Pair, v: Pair) -> Pair:
     """(U^dagger v)."""
     ur, ui = u
     vr, vi = v
-    outr = jnp.einsum("ba...,b...->a...", ur, vr) + jnp.einsum(
-        "ba...,b...->a...", ui, vi
-    )
-    outi = jnp.einsum("ba...,b...->a...", ur, vi) - jnp.einsum(
-        "ba...,b...->a...", ui, vr
-    )
-    return outr, outi
+    return (_mat_vec(ur, vr, True) + _mat_vec(ui, vi, True),
+            _mat_vec(ur, vi, True) - _mat_vec(ui, vr, True))
+
+
+def _per_spin(mult, u: Pair, h: Pair) -> Pair:
+    parts = [mult(u, (h[0][s], h[1][s])) for s in range(h[0].shape[0])]
+    return jnp.stack([p[0] for p in parts]), jnp.stack([p[1] for p in parts])
 
 
 def su3_mult_halfspinor(u: Pair, h: Pair) -> Pair:
     """(U h) with an explicit leading spin axis: u (3,3,...), h (s,3,...)."""
-    ur, ui = u
-    hr, hi = h
-    outr = jnp.einsum("ab...,sb...->sa...", ur, hr) - jnp.einsum(
-        "ab...,sb...->sa...", ui, hi
-    )
-    outi = jnp.einsum("ab...,sb...->sa...", ur, hi) + jnp.einsum(
-        "ab...,sb...->sa...", ui, hr
-    )
-    return outr, outi
+    return _per_spin(su3_mult_vec, u, h)
 
 
 def su3_adj_mult_halfspinor(u: Pair, h: Pair) -> Pair:
     """(U^dagger h) with an explicit leading spin axis."""
-    ur, ui = u
-    hr, hi = h
-    outr = jnp.einsum("ba...,sb...->sa...", ur, hr) + jnp.einsum(
-        "ba...,sb...->sa...", ui, hi
-    )
-    outi = jnp.einsum("ba...,sb...->sa...", ur, hi) - jnp.einsum(
-        "ba...,sb...->sa...", ui, hr
-    )
-    return outr, outi
+    return _per_spin(su3_adj_mult_vec, u, h)
 
 
 # ---------------------------------------------------------------------------

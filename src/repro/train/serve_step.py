@@ -13,6 +13,7 @@ and drain — one convergence-masked batched CG iteration over a fixed
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -66,16 +67,21 @@ def build_cg_serve_step(u, kappa: float, config, *, tol: float,
     bound = wilson_normal_graph(float(kappa)).bind(
         config=config, outputs=("ap", "pap"))
 
-    def apply_a_dot(p):
+    # the gauge field is an argument of the jitted step, bound here: a
+    # closed-over field would be embedded in the executable as a constant
+    # (its halo'd copy constant-folded beside it), a program too large for
+    # the persistent compile cache to keep
+    def apply_a_dot(p, u):
         out = bound({"p": p, "u": u}, out_layouts={"ap": p.layout})
         return p.with_data(out["ap"].data), out["pap"].sum(axis=-1)
 
     if refine_every <= 0:
-        def step(state):
-            return batched_cg_iteration(state, apply_a_dot, config=config,
-                                        tol=tol, max_iter=max_iter)
+        def step(state, u):
+            return batched_cg_iteration(
+                state, functools.partial(apply_a_dot, u=u), config=config,
+                tol=tol, max_iter=max_iter)
 
-        return jax.jit(step)
+        return functools.partial(jax.jit(step), u=u)
 
     hi_cfg = config_hi or (
         dataclasses.replace(config, dtypes=None)
@@ -83,24 +89,25 @@ def build_cg_serve_step(u, kappa: float, config, *, tol: float,
     bound_hi = wilson_normal_graph(float(kappa)).bind(
         config=hi_cfg, outputs=("ap", "pap"))
 
-    def apply_a_dot_hi(p):
+    def apply_a_dot_hi(p, u):
         out = bound_hi({"p": p, "u": u}, out_layouts={"ap": p.layout})
         return p.with_data(out["ap"].data), out["pap"].sum(axis=-1)
 
-    def step_refined(state, rhs):
-        state = batched_cg_iteration(state, apply_a_dot, config=config,
-                                     tol=tol, max_iter=max_iter)
+    def step_refined(state, rhs, u):
+        state = batched_cg_iteration(
+            state, functools.partial(apply_a_dot, u=u), config=config,
+            tol=tol, max_iter=max_iter)
         return jax.lax.cond(
             jnp.any(jnp.logical_and(
                 state.rr / state.b2 > tol,
                 jnp.logical_and(state.it < max_iter,
                                 state.it % refine_every == 0))),
             lambda s: batched_cg_refresh(
-                s, rhs, apply_a_dot_hi, tol=tol, max_iter=max_iter,
-                refine_every=refine_every),
+                s, rhs, functools.partial(apply_a_dot_hi, u=u), tol=tol,
+                max_iter=max_iter, refine_every=refine_every),
             lambda s: s, state)
 
-    return jax.jit(step_refined)
+    return functools.partial(jax.jit(step_refined), u=u)
 
 
 def generate(params, cfg: ArchConfig, prompt_tokens, *, steps: int,

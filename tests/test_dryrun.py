@@ -17,6 +17,7 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 def run_dryrun(arch, shape, mesh, timeout=900):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # a child never competes for the chip
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--mesh", mesh, "--smoke-arch",

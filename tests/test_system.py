@@ -68,3 +68,41 @@ def test_milc_end_to_end():
     u, b = init_problem(cfg, seed=1)
     res = solve(cfg, u, b)
     assert residual_check(cfg, u, b, res.x) < 1e-3
+
+
+def test_benchmark_runner_exits_nonzero_when_a_table_fails(monkeypatch,
+                                                            capsys):
+    """benchmarks/run.py runs every table, reports a failing one as an
+    ERROR row, and then exits 1 instead of 0."""
+    import sys
+    import types
+    from pathlib import Path
+
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]))
+    import benchmarks
+
+    names = ("table1_ridge", "fig3_kernels", "fig4_bandwidth",
+             "fig5_scaling", "lm_roofline")
+    ran, failing = [], set()
+
+    def table(name):
+        def main():
+            ran.append(name)
+            if name in failing:
+                raise RuntimeError("boom")
+        return types.SimpleNamespace(__name__=f"benchmarks.{name}",
+                                     main=main)
+
+    for name in names:
+        mod = table(name)
+        monkeypatch.setitem(sys.modules, f"benchmarks.{name}", mod)
+        monkeypatch.setattr(benchmarks, name, mod, raising=False)
+    from benchmarks import run
+
+    assert run.main() == 0
+    failing.add("fig3_kernels")
+    ran.clear()
+    assert run.main() == 1
+    assert ran == list(names)  # the tables after the failure still ran
+    assert ("benchmarks.fig3_kernels,0.0,ERROR=RuntimeError:boom"
+            in capsys.readouterr().out)

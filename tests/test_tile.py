@@ -153,6 +153,75 @@ def test_tiled_lb_step_matches_untiled(rng):
                                   np.asarray(b["dist2"].data))
 
 
+# -- the compiled path's DMA kernel, on the TPU interpreter --------------------
+
+@pytest.fixture
+def dma_on_interpreter(monkeypatch):
+    """Run compiled (interpret=False) plans — the double-buffered DMA
+    kernel every compiled stencil launch takes — on Pallas' TPU
+    interpreter, which models HBM, VMEM and async copies on the CPU.
+    Plans with interpret=True keep the plain interpreter (the whole-staged
+    reference)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.core import fuse
+
+    real = pl.pallas_call
+
+    def on_tpu_interpreter(*args, interpret=False, compiler_params=None,
+                           **kw):
+        return real(*args, interpret=interpret or pltpu.InterpretParams(),
+                    **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", on_tpu_interpreter)
+    monkeypatch.setattr(plan_mod, "_device_kind", lambda: "TPU v5 lite")
+    fuse.clear_cache()
+    yield
+    fuse.clear_cache()
+
+
+@pytest.mark.parametrize("lat,plan_kw,batch", [
+    ((6, 4, 8), dict(bx=2), 0),                 # x-slab windows, untiled
+    ((4, 16, 8), dict(bx=1, by=8), 0),          # sublane tile, window 10->16
+    ((6, 4, 8), dict(bx=1, rsplit=2), 0),       # split reduction grid
+    ((6, 4, 8), dict(bx=2), 3),                 # batch axis in the chain
+])
+def test_dma_kernel_matches_staged(lat, plan_kw, batch, rng,
+                                   dma_on_interpreter):
+    g = _graph()
+    if batch:
+        xs = rng.normal(size=(batch, 3, *lat)).astype(np.float32)
+        fx = BatchedField.from_canonical("x", xs, lat, SOA)
+    else:
+        fx = _field(rng, lat=lat)
+    staged = LoweringPlan("pallas", interpret=True, **plan_kw)
+    a = g.launch({"x": fx}, config=PCFG, outputs=("z", "zt", "zm"),
+                 plan=staged)
+    b = g.launch({"x": fx}, config=PCFG, outputs=("z", "zt", "zm"),
+                 plan=dataclasses.replace(staged, interpret=False))
+    _check(a, b)
+
+
+def test_dma_kernel_wilson_normal_4d(dma_on_interpreter):
+    """The MILC normal operator (4-D lattice, ring-2 halo, 72-comp gauge
+    input) through the DMA kernel, y-tiled: bitwise the staged kernel."""
+    from repro.apps.milc import MilcConfig, init_problem
+    from repro.apps.milc.cg import wilson_normal_graph
+
+    u, b = init_problem(MilcConfig(lattice=(4, 4, 4, 8)), seed=0)
+    g = wilson_normal_graph(0.12)
+    staged = LoweringPlan("pallas", bx=2, by=2, interpret=True)
+    a = g.launch({"p": b, "u": u}, config=PCFG, outputs=("ap", "pap"),
+                 plan=staged)
+    d = g.launch({"p": b, "u": u}, config=PCFG, outputs=("ap", "pap"),
+                 plan=dataclasses.replace(staged, interpret=False))
+    np.testing.assert_array_equal(np.asarray(a["ap"].data),
+                                  np.asarray(d["ap"].data))
+    np.testing.assert_allclose(np.asarray(a["pap"]), np.asarray(d["pap"]),
+                               rtol=1e-6)
+
+
 # -- plan axis: defaults, describe, validate, persistence ----------------------
 
 def test_by_bz_default_bit_compat():

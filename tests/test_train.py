@@ -111,3 +111,18 @@ def test_data_stream_determinism(tmp_path):
                                 seed=1, path=path))
     t, l = fs.batch(0)
     np.testing.assert_array_equal(t[:, 1:], l[:, :-1])  # shift property
+
+
+def test_append_libtpu_flags_keeps_what_the_machine_set():
+    from repro.launch.train import OVERLAP_FLAGS, append_libtpu_flags
+
+    env = {"LIBTPU_INIT_ARGS": "--xla_machine_fix=true "
+                               "--xla_tpu_enable_latency_hiding_scheduler=false"}
+    out = append_libtpu_flags(OVERLAP_FLAGS, env).split()
+    # the machine's flags stay first and win over ours
+    assert out[:2] == ["--xla_machine_fix=true",
+                       "--xla_tpu_enable_latency_hiding_scheduler=false"]
+    assert out[2:] == ["--xla_tpu_enable_async_collective_fusion=true"]
+    assert env["LIBTPU_INIT_ARGS"] == " ".join(out)
+    assert append_libtpu_flags(OVERLAP_FLAGS, {}) == " ".join(OVERLAP_FLAGS)
+

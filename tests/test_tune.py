@@ -391,7 +391,8 @@ def test_table_roundtrip_across_real_processes(tmp_path):
     import os
 
     src = str(Path(__file__).resolve().parents[1] / "src")
-    env = dict(os.environ, PYTHONPATH=src, TARGETDP_TUNE_PATH=str(table))
+    env = dict(os.environ, PYTHONPATH=src, TARGETDP_TUNE_PATH=str(table),
+               JAX_PLATFORMS="cpu")  # a child never competes for the chip
     outs = []
     for _ in range(2):
         r = subprocess.run([sys.executable, "-c", prog], env=env,

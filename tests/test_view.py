@@ -55,8 +55,9 @@ def _plans(bx, halo="periodic"):
 @pytest.mark.parametrize("sal", [2, 4])
 @pytest.mark.parametrize("bx", [1, 2, 3])
 def test_block_matches_staged_periodic(sal, bx, rng):
-    """Single-shard periodic: field output (physical array!) and on-chip
-    reduction are bitwise equal across views."""
+    """Single-shard periodic: the field output (physical array!) is
+    bitwise equal across views; the on-chip fp sum is tolerance-equal
+    (the compiler may reassociate it per view, as for rsplit and tiles)."""
     lat = (6, 4, 8)  # padded inner 6*10=60; sal 2,4 divide 60 and inner 32
     x = rng.normal(size=(3, *lat)).astype(np.float32)
     fx = Field.from_numpy("x", x, lat, aosoa(sal))
@@ -67,7 +68,11 @@ def test_block_matches_staged_periodic(sal, bx, rng):
     assert b["z"].layout == aosoa(sal)
     np.testing.assert_array_equal(np.asarray(a["z"].data),
                                   np.asarray(b["z"].data))
-    np.testing.assert_array_equal(np.asarray(a["zt"]), np.asarray(b["zt"]))
+    # the periodic Laplacian sums to ~0, so the bound is fp32 rounding
+    # relative to the summed magnitudes, per component
+    scale = np.abs(a["z"].to_numpy()).reshape(3, -1).sum(axis=1)
+    np.testing.assert_array_less(
+        np.abs(np.asarray(a["zt"]) - np.asarray(b["zt"])), 1e-6 * scale)
     # and both equal the jnp-engine oracle
     j = g.launch({"x": fx}, config=TargetConfig("jnp"), outputs=("z", "zt"))
     np.testing.assert_allclose(b["z"].to_numpy(), j["z"].to_numpy(),
