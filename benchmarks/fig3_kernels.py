@@ -639,7 +639,7 @@ def telemetry_trace(path, lattice=(32, 32, 32), engine="jnp", iters=20,
       bit for bit (spans are host-side only; enabling observability may
       never perturb the computation).
     * schema — every recorded ``launch/`` span carries the full
-      plan/engine/lattice/cache/bytes/roofline field set the README
+      plan/engine/lattice/cache/bytes field set the README
       Observability glossary documents.
 
     Returns (rows, metrics)."""
@@ -674,8 +674,7 @@ def telemetry_trace(path, lattice=(32, 32, 32), engine="jnp", iters=20,
 
     spans = telemetry.events("launch/")
     required = ("plan", "engine", "lattice", "cache", "bytes_fused",
-                "bytes_unfused", "gbps_achieved", "roofline_frac",
-                "roofline_placement")
+                "bytes_unfused")
     missing = sorted({f for s in spans for f in required
                       if f not in s["attrs"]})
     telemetry.export_chrome_trace(path)

@@ -68,7 +68,7 @@ import numpy as np
 
 from repro.core import (
     DtypePolicy, Field, LaunchGraph, Layout, SOA, TargetConfig, compat,
-    launch, target_sum, tileable_layout,
+    launch, target_sum, telemetry, tileable_layout,
 )
 from repro.kernels.lb_collision import ref as lbref
 from repro.kernels.lb_collision.ops import collide_kernel
@@ -174,7 +174,8 @@ def _mkfield(name: str, arr_nd: jnp.ndarray, cfg: LudwigConfig) -> Field:
 
 def stage_gradients(q_nd: jnp.ndarray):
     """Order Parameter Gradients."""
-    return gr.grad_central(q_nd), gr.laplacian(q_nd)
+    with telemetry.scope("ludwig/gradients"):
+        return gr.grad_central(q_nd), gr.laplacian(q_nd)
 
 
 # stage stanzas shared by every graph builder below — one definition per
@@ -237,24 +238,28 @@ def lb_step_graph(cfg: LudwigConfig) -> LaunchGraph:
 
 def stage_chemical_stress(state_q: Field, dq_nd, lapq_nd, cfg: LudwigConfig):
     """molecular field + stress (one fused launch) + force divergence."""
-    out = chem_stress_graph(cfg).bind(
-        config=cfg.target, outputs=("h", "sigma"),
-    )({"q": state_q, "lapq": _mkfield("lapq", lapq_nd, cfg),
-       "dq": _mkfield("dq", dq_nd, cfg)})
-    force_nd = gr.divergence(out["sigma"].canonical_nd())
+    with telemetry.scope("ludwig/chem_stress"):
+        out = chem_stress_graph(cfg).bind(
+            config=cfg.target, outputs=("h", "sigma"),
+        )({"q": state_q, "lapq": _mkfield("lapq", lapq_nd, cfg),
+           "dq": _mkfield("dq", dq_nd, cfg)})
+    with telemetry.scope("ludwig/force_divergence"):
+        force_nd = gr.divergence(out["sigma"].canonical_nd())
     return out["h"], force_nd
 
 
 def stage_advection(q_nd, u_nd):
     """Advection (+ periodic boundaries: no correction term)."""
-    return gr.advective_divergence(q_nd, u_nd)
+    with telemetry.scope("ludwig/advection"):
+        return gr.advective_divergence(q_nd, u_nd)
 
 
 def stage_lc_update(state_q: Field, h: Field, w_nd, adv_nd, cfg: LudwigConfig) -> Field:
-    q_new = lc_update_graph(cfg).bind(
-        config=cfg.target, outputs=("q_new",),
-    )({"q": state_q, "h": h, "w": _mkfield("w", w_nd, cfg),
-       "adv": _mkfield("adv", adv_nd, cfg)})["q_new"]
+    with telemetry.scope("ludwig/lc_update"):
+        q_new = lc_update_graph(cfg).bind(
+            config=cfg.target, outputs=("q_new",),
+        )({"q": state_q, "h": h, "w": _mkfield("w", w_nd, cfg),
+           "adv": _mkfield("adv", adv_nd, cfg)})["q_new"]
     # keep the Field name stable across steps (it is pytree aux data)
     return dataclasses.replace(q_new, name=state_q.name)
 
@@ -265,29 +270,37 @@ def _w_tensor(u_nd: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([g[b * 3 + a] for a in range(3) for b in range(3)])
 
 
+def _cast_state(dist2: Field, like: Field) -> Field:
+    """The LB launch's ``dist2`` back in the carried state's dtype and name
+    (a no-op cast unless a storage dtype narrowed the launch)."""
+    with telemetry.scope("ludwig/state_cast"):
+        return dataclasses.replace(
+            dist2.with_data(dist2.data.astype(like.data.dtype)),
+            name=like.name)
+
+
 def step(state: LudwigState, cfg: LudwigConfig) -> LudwigState:
     """One full LC-LB timestep (single shard, periodic)."""
-    q_nd = state.q.canonical_nd()
+    with telemetry.scope("ludwig/gradients"):
+        q_nd = state.q.canonical_nd()
     dq_nd, lapq_nd = stage_gradients(q_nd)
     h, force_nd = stage_chemical_stress(state.q, dq_nd, lapq_nd, cfg)
-    force = _mkfield("force", force_nd, cfg)
 
     # moments + collision + streaming fused: one halo'd launch, dist and
     # force stream from HBM once, post-collision dist never touches HBM.
     # Under cfg.storage the launch reads/writes storage-dtype bytes; the
     # carried state is cast back so the step's signature stays fixed
     # (quantization to storage precision already happened in the write).
-    lb = lb_step_graph(cfg).bind(
-        config=_lb_target(cfg), outputs=("dist2", "u"),
-    )({"dist": state.dist, "force": force})
-    dist2 = dataclasses.replace(
-        lb["dist2"].with_data(
-            lb["dist2"].data.astype(state.dist.data.dtype)),
-        name=state.dist.name)
+    with telemetry.scope("ludwig/lb"):
+        force = _mkfield("force", force_nd, cfg)
+        lb = lb_step_graph(cfg).bind(
+            config=_lb_target(cfg), outputs=("dist2", "u"),
+        )({"dist": state.dist, "force": force})
+        u_nd = lb["u"].canonical_nd().astype(q_nd.dtype)
+    dist2 = _cast_state(lb["dist2"], state.dist)
 
-    u = lb["u"]
-    u_nd = u.canonical_nd().astype(q_nd.dtype)
-    w_nd = _w_tensor(u_nd)
+    with telemetry.scope("ludwig/w_tensor"):
+        w_nd = _w_tensor(u_nd)
     adv_nd = stage_advection(q_nd, u_nd)
 
     q_new = stage_lc_update(state.q, h, w_nd, adv_nd, cfg)
@@ -318,10 +331,7 @@ def step_timed(state: LudwigState, cfg: LudwigConfig) -> Tuple[LudwigState, Dict
                                        outputs=("dist2", "u"))
     lb = timed("lb_step", lambda: lb_bound({"dist": state.dist,
                                             "force": force}))
-    dist2 = dataclasses.replace(
-        lb["dist2"].with_data(
-            lb["dist2"].data.astype(state.dist.data.dtype)),
-        name=state.dist.name)
+    dist2 = _cast_state(lb["dist2"], state.dist)
     u_nd = lb["u"].canonical_nd().astype(q_nd.dtype)
     w_nd = _w_tensor(u_nd)
     adv_nd = timed("advection", stage_advection, q_nd, u_nd)
@@ -446,20 +456,25 @@ def make_sharded_step(cfg: LudwigConfig, domain: Domain, halo: str = "pre"):
     lc_step = lc_update_graph(cfg).bind(config=tgt, outputs=("q_new",))
 
     def local_step(dist_nd, q_nd):
+        scope = telemetry.scope
         # ---- Q stencils on width-2 halo
-        qh = exchange_w(pad(q_nd, WQ), WQ)
-        dq_h = gr.grad_central(qh)
-        lapq_h = gr.laplacian(qh)
+        with scope("ludwig/gradients"):
+            qh = exchange_w(pad(q_nd, WQ), WQ)
+            dq_h = gr.grad_central(qh)
+            lapq_h = gr.laplacian(qh)
         # halo'd local Fields keep cfg.layout whenever the padded lattice
         # stays SAL-tileable (so tuned native-AoSoA plans apply sharded too)
         mk = lambda name, arr: _mkfield(name, arr, cfg)
-        qF = mk("q", qh)
-        cs = chem_step(
-            {"q": qF, "lapq": mk("lapq", lapq_h), "dq": mk("dq", dq_h)})
+        with scope("ludwig/chem_stress"):
+            qF = mk("q", qh)
+            cs = chem_step(
+                {"q": qF, "lapq": mk("lapq", lapq_h), "dq": mk("dq", dq_h)})
         h_F = cs["h"]
-        force_h = gr.divergence(cs["sigma"].canonical_nd())
-        force_nd = crop(force_h, WQ)  # interior: ring-1 div reads ring-2
-        # gradients, which wrap locally — so exchange the true force halo
+        with scope("ludwig/force_divergence"):
+            force_h = gr.divergence(cs["sigma"].canonical_nd())
+            force_nd = crop(force_h, WQ)  # interior: ring-1 div reads
+            # ring-2 gradients, which wrap locally — so exchange the true
+            # force halo
 
         # ---- fused LB half-step on pre-exchanged halos: the
         # *pre-collision* dist (and the force) is exchanged instead of the
@@ -469,38 +484,40 @@ def make_sharded_step(cfg: LudwigConfig, domain: Domain, halo: str = "pre"):
         # before the launch; halo="overlap"/None routes through the
         # overlap scheduler (interior sub-launch independent of the
         # exchange, boundary slabs after it — core.overlap).
-        if halo == "pre":
-            d_h = exchange_w(pad(dist_nd, 1, every_dim=True), 1)
-            f_h = exchange_w(pad(force_nd, 1, every_dim=True), 1)
-            lb = lb_pre_step(
-                {"dist": mk("dist", d_h), "force": mk("force", f_h)})
-        else:
-            from repro.core import overlap_launch
-            lb = overlap_launch(
-                lb_step_graph(cfg),
-                {"dist": mk("dist", pad(dist_nd, 1, every_dim=True)),
-                 "force": mk("force", pad(force_nd, 1, every_dim=True))},
-                decomposed=dec, config=tgt, outputs=("dist2", "u"),
-                halo=halo,
-            )
-        dist2_nd = lb["dist2"].canonical_nd()
+        with scope("ludwig/lb"):
+            if halo == "pre":
+                d_h = exchange_w(pad(dist_nd, 1, every_dim=True), 1)
+                f_h = exchange_w(pad(force_nd, 1, every_dim=True), 1)
+                lb = lb_pre_step(
+                    {"dist": mk("dist", d_h), "force": mk("force", f_h)})
+            else:
+                from repro.core import overlap_launch
+                lb = overlap_launch(
+                    lb_step_graph(cfg),
+                    {"dist": mk("dist", pad(dist_nd, 1, every_dim=True)),
+                     "force": mk("force", pad(force_nd, 1, every_dim=True))},
+                    decomposed=dec, config=tgt, outputs=("dist2", "u"),
+                    halo=halo,
+                )
+            dist2_nd = lb["dist2"].canonical_nd()
+            u_nd = lb["u"].canonical_nd()
 
         # ---- hydrodynamics from the pre-collision distributions
-        u_nd = lb["u"].canonical_nd()
-        uh = exchange_w(pad(u_nd, 1), 1)
-        w_h = _w_tensor(uh)
-        w_nd = crop(w_h, 1)
+        with scope("ludwig/w_tensor"):
+            uh = exchange_w(pad(u_nd, 1), 1)
+            w_nd = crop(_w_tensor(uh), 1)
         # advection: q +-1 from the wide-halo q, u faces from u halo
-        qh1 = crop(qh, WQ - 1)
-        adv_h = gr.advective_divergence(qh1, uh)
-        adv_nd = crop(adv_h, 1)
+        with scope("ludwig/advection"):
+            qh1 = crop(qh, WQ - 1)
+            adv_nd = crop(gr.advective_divergence(qh1, uh), 1)
 
         # ---- Beris-Edwards update on interior (fused rhs -> update)
-        qiF = mk("qi", q_nd)
-        q_new = lc_step(
-            {"q": qiF, "h": mk("h", crop(h_F.canonical_nd(), WQ)),
-             "w": mk("w", w_nd), "adv": mk("adv", adv_nd)})["q_new"]
-        return dist2_nd, q_new.canonical_nd()
+        with scope("ludwig/lc_update"):
+            qiF = mk("qi", q_nd)
+            q_new = lc_step(
+                {"q": qiF, "h": mk("h", crop(h_F.canonical_nd(), WQ)),
+                 "w": mk("w", w_nd), "adv": mk("adv", adv_nd)})["q_new"]
+            return dist2_nd, q_new.canonical_nd()
 
     sharded = compat.shard_map(
         local_step, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from repro.core import (
     BatchedField, Field, LaunchGraph, TargetConfig, launch, target_sum,
+    telemetry,
 )
 from repro.kernels.wilson_dslash import dslash
 from repro.kernels.wilson_dslash.ops import dslash_stencil_body
@@ -309,35 +310,46 @@ def cg(
     def gdot(x: Field, y: Field):
         return psum(dot(x, y, config))
 
-    b2 = gdot(b, b)
-    x0 = b.with_canonical(jnp.zeros_like(b.canonical()))
-    r0 = b
-    p0 = b
+    scope = telemetry.scope
+    with scope("cg/init"):
+        b2 = gdot(b, b)
+        x0 = b.with_canonical(jnp.zeros_like(b.canonical()))
+        r0 = b
+        p0 = b
+        rr0 = gdot(r0, r0)
 
     def cond(carry):
         x, r, p, rr, it = carry
-        return jnp.logical_and(rr / b2 > tol, it < max_iter)
+        with scope("cg/scalars"):
+            return jnp.logical_and(rr / b2 > tol, it < max_iter)
 
     def body(carry):
         x, r, p, rr, it = carry
-        if apply_a_dot is not None:
-            # dslash + axpy chain + <p, ap> reduction: one fused launch
-            ap, pap = apply_a_dot(p)
-            alpha = rr / psum(pap)
-        else:
-            ap = apply_a(p)
-            alpha = rr / gdot(p, ap)
+        with scope("cg/normal"):
+            if apply_a_dot is not None:
+                # dslash + axpy chain + <p, ap> reduction: one fused launch
+                ap, pap = apply_a_dot(p)
+                pap = psum(pap)
+            else:
+                ap = apply_a(p)
+                pap = gdot(p, ap)
+        with scope("cg/scalars"):
+            alpha = rr / pap
         # fused "Scalar Mult Add" chain: x/r updates + residual square +
         # its terminal sum in one launch — rr_prod never touches HBM.
-        x, r, rr_vec = fused_cg_update(x, r, p, ap, alpha, config)
-        rr_new = psum(rr_vec.sum())
-        beta = rr_new / rr
-        p = fused_xpay(r, beta, p, config)
-        return (x, r, p, rr_new, it + 1)
+        with scope("cg/update"):
+            x, r, rr_vec = fused_cg_update(x, r, p, ap, alpha, config)
+            rr_new = psum(rr_vec.sum())
+        with scope("cg/scalars"):
+            beta = rr_new / rr
+            it = it + 1
+        with scope("cg/xpay"):
+            p = fused_xpay(r, beta, p, config)
+        return (x, r, p, rr_new, it)
 
-    rr0 = gdot(r0, r0)
     x, r, p, rr, it = jax.lax.while_loop(cond, body, (x0, r0, p0, rr0, jnp.int32(0)))
-    return CGResult(x=x, iterations=it, residual=rr / b2)
+    with scope("cg/scalars"):
+        return CGResult(x=x, iterations=it, residual=rr / b2)
 
 
 def cg_refined(
@@ -374,6 +386,7 @@ def cg_refined(
     bandwidth-dominant work), matching :func:`cg`'s accounting.
     """
     hi = apply_a_dot_hi or apply_a_dot
+    scope = telemetry.scope
 
     def psum(d):
         for ax in psum_axes:
@@ -386,8 +399,9 @@ def cg_refined(
         c = f.canonical().astype(jnp.float32)
         return psum(jnp.sum(c * c))
 
-    b2 = norm2(b)
-    x0 = b.with_canonical(jnp.zeros_like(b.canonical()))
+    with scope("cg/init"):
+        b2 = norm2(b)
+        x0 = b.with_canonical(jnp.zeros_like(b.canonical()))
 
     def true_residual(x):
         ax, _ = hi(x)
@@ -396,7 +410,8 @@ def cg_refined(
 
     def cond(carry):
         _x, _r, rr, it = carry
-        return jnp.logical_and(rr / b2 > tol, it < max_iter)
+        with scope("cg/scalars"):
+            return jnp.logical_and(rr / b2 > tol, it < max_iter)
 
     def body(carry):
         x, r, rr, it = carry
@@ -404,13 +419,16 @@ def cg_refined(
                    max_iter=refine_k, psum_axes=psum_axes,
                    apply_a_dot=apply_a_dot)
         # x += d in working precision (never through a storage-dtype write)
-        x = x.with_data(x.data + inner.x.data.astype(x.data.dtype))
-        r, rr = true_residual(x)
-        return (x, r, rr, it + inner.iterations)
+        with scope("cg/refine"):
+            x = x.with_data(x.data + inner.x.data.astype(x.data.dtype))
+            r, rr = true_residual(x)
+        with scope("cg/scalars"):
+            return (x, r, rr, it + inner.iterations)
 
     x, _r, rr, it = jax.lax.while_loop(
         cond, body, (x0, b, b2, jnp.int32(0)))
-    return CGResult(x=x, iterations=it, residual=rr / b2)
+    with scope("cg/scalars"):
+        return CGResult(x=x, iterations=it, residual=rr / b2)
 
 
 # -- batched CG (multi-simulation serving) --------------------------------------
@@ -440,10 +458,11 @@ class BatchedCGResult(NamedTuple):
 def batched_cg_state(rhs: BatchedField, config: TargetConfig) -> BatchedCGState:
     """Initial state: x = 0, r = p = rhs, per-slot norms — each slot set up
     exactly as :func:`cg` sets up a single solve."""
-    b2 = batched_dot(rhs, rhs, config)
-    x0 = rhs.with_data(jnp.zeros_like(rhs.data))
-    return BatchedCGState(x=x0, r=rhs, p=rhs, rr=b2, b2=b2,
-                          it=jnp.zeros((rhs.batch,), jnp.int32))
+    with telemetry.scope("cg/init"):
+        b2 = batched_dot(rhs, rhs, config)
+        x0 = rhs.with_data(jnp.zeros_like(rhs.data))
+        return BatchedCGState(x=x0, r=rhs, p=rhs, rr=b2, b2=b2,
+                              it=jnp.zeros((rhs.batch,), jnp.int32))
 
 
 def batched_cg_active(state: BatchedCGState, *, tol: float,
@@ -470,18 +489,26 @@ def batched_cg_iteration(
     a converged/empty slot's x, r, p, rr are bitwise frozen — it stays in
     the batch without perturbing anyone's residuals until the scheduler
     drains it."""
-    act = batched_cg_active(state, tol=tol, max_iter=max_iter)
-    m = act.astype(state.r.dtype)
-    ap, pap = apply_a_dot(state.p)
-    # guard the frozen lanes' divides (their alpha/beta are never selected)
-    alpha = jnp.where(act, state.rr / jnp.where(act, pap, 1.0), 0.0)
-    x, r, rr_vec = fused_masked_cg_update(
-        state.x, state.r, state.p, ap, alpha, m, config)
-    rr_new = jnp.where(act, rr_vec.sum(axis=-1), state.rr)
-    beta = jnp.where(act, rr_new / jnp.where(act, state.rr, 1.0), 0.0)
-    p = fused_masked_xpay(r, beta, state.p, m, config)
-    return BatchedCGState(x=x, r=r, p=p, rr=rr_new, b2=state.b2,
-                          it=state.it + act.astype(state.it.dtype))
+    scope = telemetry.scope
+    with scope("cg/scalars"):
+        act = batched_cg_active(state, tol=tol, max_iter=max_iter)
+        m = act.astype(state.r.dtype)
+    with scope("cg/normal"):
+        ap, pap = apply_a_dot(state.p)
+    with scope("cg/scalars"):
+        # guard the frozen lanes' divides (their alpha/beta are never
+        # selected)
+        alpha = jnp.where(act, state.rr / jnp.where(act, pap, 1.0), 0.0)
+    with scope("cg/update"):
+        x, r, rr_vec = fused_masked_cg_update(
+            state.x, state.r, state.p, ap, alpha, m, config)
+    with scope("cg/scalars"):
+        rr_new = jnp.where(act, rr_vec.sum(axis=-1), state.rr)
+        beta = jnp.where(act, rr_new / jnp.where(act, state.rr, 1.0), 0.0)
+        it = state.it + act.astype(state.it.dtype)
+    with scope("cg/xpay"):
+        p = fused_masked_xpay(r, beta, state.p, m, config)
+    return BatchedCGState(x=x, r=r, p=p, rr=rr_new, b2=state.b2, it=it)
 
 
 def batched_cg_refresh(state: BatchedCGState, rhs: BatchedField,
@@ -496,20 +523,21 @@ def batched_cg_refresh(state: BatchedCGState, rhs: BatchedField,
     the per-iteration launches run under a bf16/fp32-storage policy — the
     recurrence residual drifts from the truth in low precision, and the
     periodic exact recompute re-aims the iteration."""
-    act = batched_cg_active(state, tol=tol, max_iter=max_iter)
-    sel = jnp.logical_and(act, state.it % refine_every == 0)
-    ax, _ = apply_a_dot_hi(state.x)
-    rt = (rhs.data.astype(jnp.float32)
-          - ax.data.astype(jnp.float32)).astype(state.r.data.dtype)
-    rr_t = state.r.with_data(rt).canonical().astype(jnp.float32)
-    rr_t = jnp.sum(rr_t * rr_t, axis=(-2, -1)).astype(state.rr.dtype)
-    selb = sel.reshape((-1,) + (1,) * (rt.ndim - 1))
-    return BatchedCGState(
-        x=state.x,
-        r=state.r.with_data(jnp.where(selb, rt, state.r.data)),
-        p=state.p.with_data(jnp.where(selb, rt, state.p.data)),
-        rr=jnp.where(sel, rr_t, state.rr),
-        b2=state.b2, it=state.it)
+    with telemetry.scope("cg/refine"):
+        act = batched_cg_active(state, tol=tol, max_iter=max_iter)
+        sel = jnp.logical_and(act, state.it % refine_every == 0)
+        ax, _ = apply_a_dot_hi(state.x)
+        rt = (rhs.data.astype(jnp.float32)
+              - ax.data.astype(jnp.float32)).astype(state.r.data.dtype)
+        rr_t = state.r.with_data(rt).canonical().astype(jnp.float32)
+        rr_t = jnp.sum(rr_t * rr_t, axis=(-2, -1)).astype(state.rr.dtype)
+        selb = sel.reshape((-1,) + (1,) * (rt.ndim - 1))
+        return BatchedCGState(
+            x=state.x,
+            r=state.r.with_data(jnp.where(selb, rt, state.r.data)),
+            p=state.p.with_data(jnp.where(selb, rt, state.p.data)),
+            rr=jnp.where(sel, rr_t, state.rr),
+            b2=state.b2, it=state.it)
 
 
 def cg_batched(

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from repro.core import (
     BatchedField, DtypePolicy, Field, Layout, SOA, TargetConfig, compat,
-    overlap_launch, tileable_layout,
+    overlap_launch, telemetry, tileable_layout,
 )
 from repro.core import halo as halo_mod
 from repro.kernels.wilson_dslash.ops import dslash_halo
@@ -122,7 +122,8 @@ def solve(cfg: MilcConfig, u: Field, b: Field) -> CGResult:
     against the policy-free operator recover the working-precision
     tolerance."""
     apply_m, apply_mdag, apply_normal = make_wilson_op(u, cfg.kappa, cfg.target)
-    rhs = apply_mdag(b)
+    with telemetry.scope("milc/rhs"):
+        rhs = apply_mdag(b)
     rk = _refine_k(cfg)
     if rk > 0:
         return cg_refined(
@@ -149,10 +150,9 @@ def solve_batched(cfg: MilcConfig, u: Field, bs) -> BatchedCGResult:
     single-lattice M^dag path before stacking, and converged slots are
     frozen by select-masking, never arithmetic (see cg._masked_fma_body)."""
     _, apply_mdag, _ = make_wilson_op(u, cfg.kappa, cfg.target)
-    if isinstance(bs, BatchedField):
-        rhs = BatchedField.stack(
-            [apply_mdag(b) for b in bs.unstack()], name="rhs")
-    else:
+    with telemetry.scope("milc/rhs"):
+        if isinstance(bs, BatchedField):
+            bs = bs.unstack()
         rhs = BatchedField.stack([apply_mdag(b) for b in bs], name="rhs")
     rk = _refine_k(cfg)
     return cg_batched(

@@ -16,7 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from .layout import Layout, SOA
+
+# every layout view and relayout below issues its device ops under this
+# scope (core.telemetry), so a device trace tells them from kernels
+_RELAYOUT = "field/relayout"
 
 __all__ = ["Field", "BatchedField"]
 
@@ -46,11 +51,12 @@ class Field:
     @classmethod
     def from_canonical(cls, name, canonical, lattice, layout=SOA):
         """canonical: (ncomp, *lattice) or (ncomp, nsites)."""
-        canonical = jnp.asarray(canonical)
-        ncomp = canonical.shape[0]
-        nsites = math.prod(lattice)
-        flat = canonical.reshape(ncomp, nsites)
-        return cls(name, ncomp, tuple(lattice), layout, layout.pack(flat))
+        with telemetry.scope(_RELAYOUT):
+            canonical = jnp.asarray(canonical)
+            ncomp = canonical.shape[0]
+            nsites = math.prod(lattice)
+            flat = canonical.reshape(ncomp, nsites)
+            return cls(name, ncomp, tuple(lattice), layout, layout.pack(flat))
 
     @classmethod
     def from_numpy(cls, name, array_cs, lattice, layout=SOA, dtype=jnp.float32):
@@ -68,11 +74,14 @@ class Field:
 
     def canonical(self) -> jax.Array:
         """(ncomp, nsites) logical view (layout-independent)."""
-        return self.layout.unpack(self.data)
+        with telemetry.scope(_RELAYOUT):
+            return self.layout.unpack(self.data)
 
     def canonical_nd(self) -> jax.Array:
         """(ncomp, *lattice) logical view — stencil/geometry operations."""
-        return self.canonical().reshape((self.ncomp,) + self.lattice)
+        with telemetry.scope(_RELAYOUT):
+            return self.layout.unpack(self.data).reshape(
+                (self.ncomp,) + self.lattice)
 
     def to_numpy(self) -> np.ndarray:
         return np.asarray(self.canonical_nd())
@@ -83,16 +92,18 @@ class Field:
         return dataclasses.replace(self, data=data)
 
     def with_canonical(self, canonical: jax.Array) -> "Field":
-        flat = canonical.reshape(self.ncomp, self.nsites)
-        return dataclasses.replace(self, data=self.layout.pack(flat))
+        with telemetry.scope(_RELAYOUT):
+            flat = canonical.reshape(self.ncomp, self.nsites)
+            return dataclasses.replace(self, data=self.layout.pack(flat))
 
     def as_layout(self, layout: Layout) -> "Field":
         """Relayout (the paper's per-architecture layout switch)."""
         if layout == self.layout:
             return self
-        return dataclasses.replace(
-            self, layout=layout, data=layout.pack(self.canonical())
-        )
+        with telemetry.scope(_RELAYOUT):
+            return dataclasses.replace(
+                self, layout=layout, data=layout.pack(self.canonical())
+            )
 
     def __repr__(self) -> str:  # pragma: no cover - cosmetic
         return (
@@ -148,12 +159,13 @@ class BatchedField:
     @classmethod
     def from_canonical(cls, name, canonical, lattice, layout=SOA):
         """canonical: (batch, ncomp, *lattice) or (batch, ncomp, nsites)."""
-        canonical = jnp.asarray(canonical)
-        batch, ncomp = canonical.shape[:2]
-        nsites = math.prod(lattice)
-        flat = canonical.reshape(batch, ncomp, nsites)
-        return cls(name, batch, ncomp, tuple(lattice), layout,
-                   jax.vmap(layout.pack)(flat))
+        with telemetry.scope(_RELAYOUT):
+            canonical = jnp.asarray(canonical)
+            batch, ncomp = canonical.shape[:2]
+            nsites = math.prod(lattice)
+            flat = canonical.reshape(batch, ncomp, nsites)
+            return cls(name, batch, ncomp, tuple(lattice), layout,
+                       jax.vmap(layout.pack)(flat))
 
     # -- views -----------------------------------------------------------------
 
@@ -175,11 +187,14 @@ class BatchedField:
 
     def canonical(self) -> jax.Array:
         """(batch, ncomp, nsites) logical view."""
-        return jax.vmap(self.layout.unpack)(self.data)
+        with telemetry.scope(_RELAYOUT):
+            return jax.vmap(self.layout.unpack)(self.data)
 
     def canonical_nd(self) -> jax.Array:
         """(batch, ncomp, *lattice) logical view."""
-        return self.canonical().reshape((self.batch, self.ncomp) + self.lattice)
+        with telemetry.scope(_RELAYOUT):
+            return jax.vmap(self.layout.unpack)(self.data).reshape(
+                (self.batch, self.ncomp) + self.lattice)
 
     def to_numpy(self) -> np.ndarray:
         return np.asarray(self.canonical_nd())

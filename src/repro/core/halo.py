@@ -20,6 +20,8 @@ from typing import Sequence, Tuple
 import jax
 from jax import lax
 
+from . import telemetry
+
 __all__ = [
     "exchange_dim",
     "exchange",
@@ -72,15 +74,15 @@ def exchange_dim(
             f"halo exchange of dim {dim}: local halo'd extent {L} is too "
             f"thin for width {width} (interior {L - 2 * width} < width; "
             f"need extent >= {3 * width})")
-    lo_interior = _take(x, dim, width, 2 * width)
-    hi_interior = _take(x, dim, L - 2 * width, L - width)
-    # my high interior -> right neighbour's low halo
-    recv_lo = lax.ppermute(hi_interior, axis_name, perm=fwd)
-    # my low interior -> left neighbour's high halo
-    recv_hi = lax.ppermute(lo_interior, axis_name, perm=bwd)
-    x = _put(x, dim, 0, width, recv_lo)
-    x = _put(x, dim, L - width, L, recv_hi)
-    return x
+    with telemetry.scope("halo/exchange"):
+        lo_interior = _take(x, dim, width, 2 * width)
+        hi_interior = _take(x, dim, L - 2 * width, L - width)
+        # my high interior -> right neighbour's low halo
+        recv_lo = lax.ppermute(hi_interior, axis_name, perm=fwd)
+        # my low interior -> left neighbour's high halo
+        recv_hi = lax.ppermute(lo_interior, axis_name, perm=bwd)
+        x = _put(x, dim, 0, width, recv_lo)
+        return _put(x, dim, L - width, L, recv_hi)
 
 
 def exchange(

@@ -208,38 +208,40 @@ def _split_launch(
     # spans make the split schedule visible as a trace (core.telemetry);
     # the nested launch/* spans are the sub-launches themselves.
     gname = getattr(graph, "name", "?")
-    with telemetry.span("overlap/interior", graph=gname,
-                        box=str(interior_box)):
-        results = [(interior_box, launch_box(interior_box, ins_interior))]
-    for box in boundary:
-        with telemetry.span("overlap/boundary", graph=gname, box=str(box)):
-            results.append((box, launch_box(box, ins_boundary)))
+    # the windows and the assembly are the launch layer's staging
+    with telemetry.scope(f"launch/{gname}"):
+        with telemetry.span("overlap/interior", graph=gname,
+                            box=str(interior_box)):
+            results = [(interior_box, launch_box(interior_box, ins_interior))]
+        for box in boundary:
+            with telemetry.span("overlap/boundary", graph=gname, box=str(box)):
+                results.append((box, launch_box(box, ins_boundary)))
 
-    batch = max((int(getattr(ins_boundary[n], "batch", 0)) for n in ext),
-                default=0)
-    out: Dict[str, Union[Field, jax.Array]] = {}
-    for o in field_outputs:
-        first_val = results[0][1][o]
-        ncomp, dtype = first_val.ncomp, first_val.dtype
-        lead = (batch, ncomp) if batch else (ncomp,)
-        acc = jnp.zeros(lead + lattice, dtype)
-        for box, res in results:
-            starts = (0,) * len(lead) + tuple(s for (s, _) in box)
-            acc = jax.lax.dynamic_update_slice(
-                acc, res[o].canonical_nd(), starts)
-        if batch:
-            out[o] = BatchedField.from_canonical(o, acc, lattice,
-                                                 out_layouts[o])
-        else:
-            out[o] = Field.from_canonical(o, acc, lattice, out_layouts[o])
-    for o in red_outputs:
-        # per-slab partials merge through the shared stage-2 combine
-        # (ReduceSpec.combine_partials) — the same deterministic
-        # segment-order fold the split-reduction (rsplit) lowering uses,
-        # stacked in slab order (interior first, then boundary slabs)
-        parts = jnp.stack([res[o] for _, res in results])
-        out[o] = red_specs[o].combine_partials(parts, axis=0)
-    return out
+        batch = max((int(getattr(ins_boundary[n], "batch", 0)) for n in ext),
+                    default=0)
+        out: Dict[str, Union[Field, jax.Array]] = {}
+        for o in field_outputs:
+            first_val = results[0][1][o]
+            ncomp, dtype = first_val.ncomp, first_val.dtype
+            lead = (batch, ncomp) if batch else (ncomp,)
+            acc = jnp.zeros(lead + lattice, dtype)
+            for box, res in results:
+                starts = (0,) * len(lead) + tuple(s for (s, _) in box)
+                acc = jax.lax.dynamic_update_slice(
+                    acc, res[o].canonical_nd(), starts)
+            if batch:
+                out[o] = BatchedField.from_canonical(o, acc, lattice,
+                                                     out_layouts[o])
+            else:
+                out[o] = Field.from_canonical(o, acc, lattice, out_layouts[o])
+        for o in red_outputs:
+            # per-slab partials merge through the shared stage-2 combine
+            # (ReduceSpec.combine_partials) — the same deterministic
+            # segment-order fold the split-reduction (rsplit) lowering uses,
+            # stacked in slab order (interior first, then boundary slabs)
+            parts = jnp.stack([res[o] for _, res in results])
+            out[o] = red_specs[o].combine_partials(parts, axis=0)
+        return out
 
 
 def execute_split(

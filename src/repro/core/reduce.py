@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import telemetry
 from .field import Field  # noqa: F401  (re-exported reduction operand type)
 from .fuse import ReduceSpec, kahan_fold
 from .plan import plan_for_launch, resolve_accumulate
@@ -38,6 +39,13 @@ __all__ = ["target_sum", "target_max"]
 
 
 def _reduce(field, config: Optional[TargetConfig], op: str) -> jax.Array:
+    # the reduction launch's device ops carry its scope (core.telemetry)
+    with telemetry.scope(f"launch/target_{op}"):
+        return _reduce_launch(field, config, op)
+
+
+def _reduce_launch(field, config: Optional[TargetConfig],
+                   op: str) -> jax.Array:
     config = config or TargetConfig()
     spec = ReduceSpec(op=op)
     batch = int(getattr(field, "batch", 0))

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import telemetry
 from .field import Field
 from .layout import Layout
 from .plan import (  # noqa: F401  (re-exported: the planning layer owns them)
@@ -473,17 +474,21 @@ def launch(
         first.nsites,
         [f.layout for f in ins.values()] + [out_layouts[k] for k in out_specs],
     )
-    if plan.engine == "jnp":
-        outs = kern._run_jnp(ins, params)
-    else:  # "pallas" (plan_for_launch validated the engine)
-        outs = kern._run_pallas(
-            ins, out_specs, params, plan=plan, out_layouts=out_layouts
-        )
+    # the launch layer's device ops carry the kernel's scope
+    # (core.telemetry), as every LaunchGraph launch's do
+    with telemetry.scope(f"launch/{kern.name}"):
+        if plan.engine == "jnp":
+            outs = kern._run_jnp(ins, params)
+        else:  # "pallas" (plan_for_launch validated the engine)
+            outs = kern._run_pallas(
+                ins, out_specs, params, plan=plan, out_layouts=out_layouts
+            )
 
-    fields = {}
-    for k, (ncomp, dtype) in out_specs.items():
-        arr = outs[k].astype(dtype)
-        fields[k] = Field(
-            k, ncomp, first.lattice, out_layouts[k], out_layouts[k].pack(arr)
-        )
-    return fields
+        fields = {}
+        for k, (ncomp, dtype) in out_specs.items():
+            arr = outs[k].astype(dtype)
+            fields[k] = Field(
+                k, ncomp, first.lattice, out_layouts[k],
+                out_layouts[k].pack(arr)
+            )
+        return fields

@@ -1,8 +1,11 @@
-"""Process-wide telemetry: counters, spans, JSONL sink, Chrome-trace export.
+"""Process-wide telemetry: counters, spans, device-op scopes, Chrome-trace
+export.
 
 The paper assesses every port "within the context of the Roofline model"
-(§5); this module makes that assessment *live*.  Every hot seam of the
-stack is instrumented against one registry:
+(§5); the device time that assessment divides by comes from a profiler
+trace, and this module is what lets such a trace name the program's
+layers.  Every hot seam of the stack is instrumented against one
+registry:
 
 * **counters** — monotonically increasing named integers.  Always on:
   they are the same dict increments the old ``fuse._STATS`` /
@@ -12,10 +15,17 @@ stack is instrumented against one registry:
   Recorded only while telemetry is enabled.
 * **spans** — timed intervals with attributes (one per ``LaunchGraph``
   launch, tuner candidate, overlap sub-launch, pipeline step, serve
-  request).  Launch spans carry the resolved plan label, cache hit/miss,
-  the modeled HBM bytes of ``LaunchGraph.bytes_moved`` and a live
-  roofline placement against the ``launch/roofline.py`` ceilings.
+  request).  Launch spans carry the resolved plan label, cache hit/miss
+  and the modeled HBM bytes of ``LaunchGraph.bytes_moved``.  While a span
+  records it also holds a ``jax.profiler.TraceAnnotation`` of its name, so
+  under a profiler trace it lands on the host plane, on the device
+  trace's clock.
 * **events** — zero-duration instants (pruned/failed tune candidates).
+* **scopes** — :func:`scope` names the device ops traced inside it
+  (``jax.named_scope``), so a device trace can put each op down to the
+  program layer that issued it.  :data:`SCOPE_ROOTS` lists the roots.
+  Scopes are HLO metadata, fixed at trace time and free at run time, so
+  they are never gated.
 
 Gating: the module switch starts from ``$TARGETDP_TELEMETRY`` (1/true/on
 /yes) and is flipped at runtime with :func:`enable` / :func:`disable`;
@@ -29,10 +39,9 @@ single bit of any launch output.
 
 Export: :func:`export_chrome_trace` writes the Chrome trace-event JSON
 (``{"traceEvents": [...]}``) that Perfetto / ``chrome://tracing`` load
-directly; :func:`write_jsonl` (or the live sink of ``enable(jsonl=...)``)
-streams one JSON object per finished span.  :func:`report` returns the
-aggregate snapshot; :func:`configure_logging` wires every ``repro.*``
-child logger through one stderr handler.
+directly.  :func:`report` returns the aggregate snapshot;
+:func:`configure_logging` wires every ``repro.*`` child logger through
+one stderr handler.
 """
 
 from __future__ import annotations
@@ -44,8 +53,13 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 __all__ = [
     "ENV_VAR",
+    "SCOPE_ROOTS",
+    "STAGE_SCOPES",
+    "scope",
     "enabled",
     "enable",
     "disable",
@@ -63,8 +77,6 @@ __all__ = [
     "report",
     "format_report",
     "export_chrome_trace",
-    "write_jsonl",
-    "roofline_placement",
     "configure_logging",
 ]
 
@@ -84,7 +96,6 @@ _enabled: bool = _env_enabled(os.environ.get(ENV_VAR))
 _counters: Dict[str, int] = {}
 _gauges: Dict[str, List[tuple]] = {}  # name -> [(ts, value), ...]
 _events: List[dict] = []  # finished spans + instants, in finish order
-_jsonl: Optional[Any] = None  # open file object of the live sink
 _T0 = time.perf_counter()  # trace time base (relative perf_counter)
 _MAX_EVENTS = 500_000  # hard cap: long serve runs must not grow unbounded
 _dropped = 0
@@ -98,27 +109,17 @@ def enabled(override: Optional[bool] = None) -> bool:
     return _enabled
 
 
-def enable(jsonl: Optional[str] = None) -> None:
-    """Turn span/gauge recording on (optionally streaming finished spans
-    to a JSONL file at ``jsonl``, one JSON object per line)."""
-    global _enabled, _jsonl
-    with _lock:
-        _enabled = True
-        if jsonl is not None:
-            if _jsonl is not None:
-                _jsonl.close()
-            _jsonl = open(jsonl, "a")
+def enable() -> None:
+    """Turn span/gauge recording on."""
+    global _enabled
+    _enabled = True
 
 
 def disable() -> None:
     """Turn span/gauge recording off (counters keep counting — they are
-    the pre-telemetry ``stats()`` probes) and close any JSONL sink."""
-    global _enabled, _jsonl
-    with _lock:
-        _enabled = False
-        if _jsonl is not None:
-            _jsonl.close()
-            _jsonl = None
+    the pre-telemetry ``stats()`` probes)."""
+    global _enabled
+    _enabled = False
 
 
 # -- counters (always on) ------------------------------------------------------
@@ -166,19 +167,43 @@ def gauges(prefix: Optional[str] = None) -> Dict[str, List[tuple]]:
     return {k: list(v) for k, v in _gauges.items() if k.startswith(prefix)}
 
 
+# -- device-op scopes (never gated) ------------------------------------------
+
+# The roots of every scope name: the launch layer (``launch/<graph>``, with
+# ``stage_in``/``stage_out`` inside its jitted body) and the program layers
+# around it.  A device trace reduction buckets each op by the roots in its
+# name stack, so a name outside these tuples is refused here.
+SCOPE_ROOTS = ("launch", "ludwig", "milc", "cg", "field", "halo")
+STAGE_SCOPES = ("stage_in", "stage_out")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``: every op traced inside carries ``name``
+    in its metadata (``op_name``, the device trace's ``tf_op``).  Trace-time
+    only — nothing runs per call — so it is independent of :func:`enabled`."""
+    if name.split("/", 1)[0] not in SCOPE_ROOTS and name not in STAGE_SCOPES:
+        raise ValueError(f"scope {name!r} is under no root of SCOPE_ROOTS "
+                         f"{SCOPE_ROOTS} and is not one of {STAGE_SCOPES}")
+    return jax.named_scope(name)
+
+
 # -- spans (gated) -------------------------------------------------------------
 
 class Span:
     """One timed interval.  Use as a context manager (``with span(...)``)
     or manually via :func:`begin_span` / :meth:`end`.  ``set()`` attaches
     attributes mid-flight (e.g. cache hit/miss discovered during the
-    launch, achieved GB/s computed after it)."""
+    launch).  From open to :meth:`end` it holds a profiler annotation of
+    its name, which needs no lexical nesting: spans may close in any
+    order."""
 
-    __slots__ = ("name", "attrs", "t0", "t1")
+    __slots__ = ("name", "attrs", "t0", "t1", "_ann")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
+        self._ann = jax.profiler.TraceAnnotation(name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter() - _T0
         self.t1: Optional[float] = None
 
@@ -199,6 +224,7 @@ class Span:
             return
         self.attrs.update(attrs)
         self.t1 = time.perf_counter() - _T0
+        self._ann.__exit__(None, None, None)
         _record({
             "type": "span",
             "name": self.name,
@@ -280,9 +306,6 @@ def _record(rec: dict) -> None:
             _dropped += 1
             return
         _events.append(rec)
-        if _jsonl is not None:
-            _jsonl.write(json.dumps(rec, default=str) + "\n")
-            _jsonl.flush()
 
 
 def events(name_prefix: Optional[str] = None) -> List[dict]:
@@ -304,36 +327,6 @@ def reset() -> None:
         _dropped = 0
     _gauges.clear()
     _counters.clear()
-
-
-# -- roofline placement --------------------------------------------------------
-
-_HBM_BW: Optional[float] = None
-
-
-def roofline_placement(bytes_moved: int, seconds: float) -> Dict[str, Any]:
-    """Live roofline fields for a launch span: achieved GB/s from the
-    modeled HBM bytes over the measured wall interval, as a fraction of
-    the ``launch/roofline.py`` HBM ceiling.  The stack's kernels sit far
-    below every ridge point (paper C4, fig 4), so the HBM bandwidth roof
-    is the binding ceiling — ``placement`` names it with the achieved
-    fraction.  Host-side wall time includes dispatch/interpret overhead;
-    on real hardware the fraction approaches the paper's %STREAM."""
-    global _HBM_BW
-    if _HBM_BW is None:
-        from repro.launch.roofline import HBM_BW
-        _HBM_BW = HBM_BW
-
-    gbps = (bytes_moved / seconds / 1e9) if seconds > 0 else 0.0
-    ceiling = _HBM_BW / 1e9
-    frac = gbps / ceiling if ceiling else 0.0
-    return {
-        "gbps_achieved": gbps,
-        "roofline_ceiling_gbps": ceiling,
-        "roofline_frac": frac,
-        "roofline_placement": (
-            f"memory-roof {frac * 100:.2f}% of {ceiling:.0f} GB/s HBM"),
-    }
 
 
 # -- reporting / export --------------------------------------------------------
@@ -429,15 +422,6 @@ def export_chrome_trace(path: str) -> str:
     with open(path, "w") as f:
         json.dump({"traceEvents": trace_events, "displayTimeUnit": "ms"},
                   f, indent=1)
-    return path
-
-
-def write_jsonl(path: str) -> str:
-    """Dump every recorded span/instant to ``path``, one JSON object per
-    line (the batch form of the ``enable(jsonl=...)`` live sink)."""
-    with open(path, "w") as f:
-        for e in events():
-            f.write(json.dumps(e, default=str) + "\n")
     return path
 
 

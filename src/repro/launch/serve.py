@@ -168,9 +168,13 @@ class _Bucket:
         self.iterations_run += 1
         telemetry.inc("serve.ticks")
         telemetry.inc(f"serve.ticks.{self.label}")
-        act = np.asarray(
-            batched_cg_active(self.state, tol=self.tol,
-                              max_iter=self.max_iter))
+        # the host waits here for the tick's device work: its own span,
+        # so a served trace tells waiting from dispatch
+        with telemetry.span("serve/sync", bucket=self.label,
+                            tick=self.iterations_run):
+            act = np.asarray(
+                batched_cg_active(self.state, tol=self.tol,
+                                  max_iter=self.max_iter))
         done = {}
         for slot in range(self.slots):
             if self.slot_rid[slot] is not None and not act[slot]:

@@ -198,6 +198,33 @@ def test_drain_telemetry_matches_oracle_trace_and_disabled_is_bitwise():
     assert len(telemetry.events("serve/tick")) == total_ticks
 
 
+def test_serve_sync_span_brackets_the_harvest():
+    """Each tick's host wait for its device work (the harvest's liveness
+    read) is a ``serve/sync`` span of its own, after the tick's dispatch
+    span and before the next tick: one per tick, per bucket."""
+    from repro.core import telemetry
+
+    telemetry.disable()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        server = _mixed_shape_server()
+        server.run()
+    finally:
+        telemetry.disable()
+    ticks = telemetry.events("serve/tick")
+    syncs = telemetry.events("serve/sync")
+    assert len(syncs) == len(ticks) == sum(
+        b.iterations_run for b in server.buckets.values())
+    for t, s in zip(ticks, syncs):
+        assert s["attrs"]["bucket"] == t["attrs"]["bucket"]
+        assert s["attrs"]["tick"] == t["attrs"]["tick"]
+        assert s["ts"] >= t["ts"] + t["dur"]
+    for s, t_next in zip(syncs, ticks[1:]):
+        assert t_next["ts"] >= s["ts"] + s["dur"]
+    telemetry.reset()
+
+
 def test_scheduler_rejects_unregistered_shape():
     cfg = _cfg("jnp")
     server = SolveServer(cfg.target)

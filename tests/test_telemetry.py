@@ -1,14 +1,19 @@
 """core.telemetry: the unified observability registry.  Disabled no-op
 path, gated spans/gauges vs always-on counters, the fuse/tune stats()
-back-compat shims, launch-span schema with cache transitions and live
-roofline placement, per-launch TargetConfig.telemetry override, Chrome
-trace + JSONL export, report snapshots, the unified repro.* logging tree
+back-compat shims, launch-span schema with cache transitions, per-launch
+TargetConfig.telemetry override, Chrome trace export, report snapshots,
+spans on the profiler's host plane, device-op scopes covering every op of
+the Ludwig step and the MILC solve, the unified repro.* logging tree
 (tuner candidate failures, overlap thin-interior fallback, tuned-misfit
 degrade — all caplog-asserted), tune sweep spans and pipeline step spans.
 """
 
+import collections
+import functools
+import glob
 import json
 import logging
+import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -121,8 +126,7 @@ def test_stats_shims_exact_keys_and_scoped_reset():
 
 LAUNCH_SPAN_SCHEMA = (
     "plan", "engine", "lattice", "batch", "halo", "from_tuned_table",
-    "cache", "bytes_fused", "bytes_unfused", "gbps_achieved",
-    "roofline_ceiling_gbps", "roofline_frac", "roofline_placement",
+    "cache", "bytes_fused", "bytes_unfused",
 )
 
 
@@ -150,8 +154,6 @@ def test_launch_span_schema_cache_transition_and_bitwise(rng):
     assert a["engine"] == "jnp"
     assert a["lattice"] == str(LAT)
     assert a["bytes_fused"] > 0 and a["bytes_unfused"] >= a["bytes_fused"]
-    assert a["gbps_achieved"] > 0 and a["roofline_frac"] > 0
-    assert "memory-roof" in a["roofline_placement"]
     assert a["from_tuned_table"] is False
 
 
@@ -187,24 +189,6 @@ def test_chrome_trace_export(tmp_path):
     assert c["name"] == "probe.gauge"
 
 
-def test_jsonl_sinks(tmp_path):
-    live = tmp_path / "live.jsonl"
-    telemetry.enable(jsonl=str(live))
-    with telemetry.span("probe/a"):
-        pass
-    telemetry.disable()  # closes the live sink
-    lines = [json.loads(ln) for ln in live.read_text().splitlines()]
-    assert [ln["name"] for ln in lines] == ["probe/a"]
-
-    telemetry.enable()
-    with telemetry.span("probe/b"):
-        pass
-    batch = tmp_path / "batch.jsonl"
-    telemetry.write_jsonl(str(batch))
-    names = [json.loads(ln)["name"] for ln in batch.read_text().splitlines()]
-    assert "probe/b" in names
-
-
 def test_report_and_format():
     telemetry.enable()
     telemetry.inc("probe.count", 3)
@@ -222,14 +206,191 @@ def test_report_and_format():
     assert "probe.count" in txt and "probe/s" in txt
 
 
-def test_roofline_placement_fields():
-    from repro.launch.roofline import HBM_BW
+# -- the profiler's clock ------------------------------------------------------
 
-    r = telemetry.roofline_placement(int(HBM_BW), 1.0)  # exactly the roof
-    assert r["gbps_achieved"] == pytest.approx(HBM_BW / 1e9)
-    assert r["roofline_frac"] == pytest.approx(1.0)
-    assert "memory-roof" in r["roofline_placement"]
-    assert telemetry.roofline_placement(100, 0.0)["gbps_achieved"] == 0.0
+def _host_events(trace_dir):
+    """{name: [(start_ns, end_ns)]} of the host planes of the one
+    ``.xplane.pb`` a ``jax.profiler.trace`` wrote under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out[e.name].append((e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def test_spans_reach_the_profiler_host_plane(tmp_path):
+    """A recording span is also a profiler annotation: a lexical span and
+    two begin/end spans that close out of order appear by name on a host
+    plane, on the trace's clock, each once."""
+    import jax
+
+    telemetry.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.span("probe/lexical"):
+            jnp.ones(8).block_until_ready()
+        first = telemetry.begin_span("probe/first")
+        second = telemetry.begin_span("probe/second")
+        first.end()  # closes while the span opened after it is open
+        second.end()
+    ev = _host_events(str(tmp_path))
+    (lex,) = ev["probe/lexical"]
+    (a,) = ev["probe/first"]
+    (b,) = ev["probe/second"]
+    assert lex[1] <= a[0]
+    assert a[0] <= b[0] <= a[1] <= b[1]  # overlapping, not nested
+    assert [e["name"] for e in telemetry.events("probe/")] == [
+        "probe/lexical", "probe/first", "probe/second"]
+
+
+class _CountingAnnotation:
+    made = []
+
+    def __init__(self, name):
+        self.made.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+def test_disabled_path_makes_no_annotation(rng, monkeypatch):
+    """With telemetry off, ``span``/``begin_span`` hand back the shared
+    NULL_SPAN and no profiler annotation is made — not even by a launch;
+    switched on, each span makes exactly one."""
+    import jax
+
+    monkeypatch.setattr(_CountingAnnotation, "made", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    assert telemetry.span("probe/off") is telemetry.NULL_SPAN
+    assert telemetry.begin_span("probe/off2") is telemetry.NULL_SPAN
+    with telemetry.span("probe/off3") as s:
+        s.set(k=1).end()
+    _graph("silent").launch({"x": _field(rng)}, config=TargetConfig("jnp"))
+    assert _CountingAnnotation.made == []
+    assert telemetry.events() == []
+
+    telemetry.enable()
+    with telemetry.span("probe/on"):
+        pass
+    assert _CountingAnnotation.made == ["probe/on"]
+
+
+# -- device-op scopes ------------------------------------------------------------
+
+def test_scope_names_are_declared():
+    assert telemetry.SCOPE_ROOTS == (
+        "launch", "ludwig", "milc", "cg", "field", "halo")
+    for name in ("launch/g", "cg/xpay", "stage_in", "stage_out"):
+        with telemetry.scope(name):
+            pass
+    for name in ("bogus/x", "stage", "ludwigs/lb"):
+        with pytest.raises(ValueError, match="SCOPE_ROOTS"):
+            telemetry.scope(name)
+
+
+_HLO_COMP = re.compile(r"^(ENTRY )?%?([\w.\-]+) .*\{$")
+_HLO_INST = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\((.*)")
+_HLO_CALLS = re.compile(r"\b(to_apply|body|condition)=%?([\w.\-]+)")
+_HLO_OPNAME = re.compile(r'op_name="([^"]*)"')
+# ops with no layer of their own: arguments, literals (and their
+# broadcasts), tuple plumbing and the loop op, whose body is checked
+_PLUMBING = ("parameter", "constant", "tuple", "get-tuple-element", "while")
+
+
+def _full_op_names(hlo_text):
+    """(opcode, operands, op_name) of every op of a lowered module, each
+    op_name with its callers' names in front, as XLA writes them when it
+    inlines a ``call`` (a jitted function's computation holds names
+    relative to it; loop bodies share the enclosing function's)."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            m = _HLO_COMP.match(line)
+            if m and not line.startswith(" "):
+                cur = m.group(2)
+                comps[cur] = []
+                entry = cur if m.group(1) else entry
+            continue
+        if line.strip() == "}":
+            cur = None
+            continue
+        m = _HLO_INST.match(line)
+        if m:
+            on = _HLO_OPNAME.search(line)
+            comps[cur].append((m.group(1), m.group(2),
+                               on.group(1) if on else "",
+                               _HLO_CALLS.findall(line)))
+    out, seen = [], set()
+
+    def walk(comp, prefix):
+        if (comp, prefix) in seen:
+            return
+        seen.add((comp, prefix))
+        for opc, operands, name, called in comps[comp]:
+            full = "/".join(p for p in (prefix, name) if p)
+            out.append((opc, operands, full))
+            for kind, callee in called:
+                if opc == "call":
+                    walk(callee, full)
+                elif kind in ("body", "condition"):
+                    walk(callee, prefix)
+                # other to_apply computations are reducers, not device ops
+
+    walk(entry, "")
+    return out
+
+
+def _lowered_for_chip(app, monkeypatch):
+    """The tiny Ludwig step or MILC solve lowered as the chip gets it:
+    compiled Pallas (Mosaic custom calls, DMA-staged stencil launches),
+    lowered for a TPU from this CPU host."""
+    from repro.apps.ludwig import driver as lud
+    from repro.apps.milc import driver as milc
+    from repro.core import plan as plan_mod
+
+    monkeypatch.setattr(plan_mod, "_device_kind", lambda: "TPU v5 lite")
+    tgt = TargetConfig("pallas", interpret=False)
+    if app == "ludwig":
+        cfg = lud.LudwigConfig(lattice=(8, 8, 128), target=tgt)
+        fn, args = functools.partial(lud.step, cfg=cfg), (lud.init_state(cfg),)
+    else:
+        cfg = milc.MilcConfig(lattice=(4, 4, 8, 16), target=tgt, max_iter=5)
+        fn, args = functools.partial(milc.solve, cfg), milc.init_problem(cfg)
+    import jax
+
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("app", ["ludwig", "milc"])
+def test_every_device_op_is_scoped(app, monkeypatch):
+    """Every op the chip would run carries a scope root in its name stack
+    (so a device trace can name the layer that issued it), the launches'
+    bodies are named after their graphs, and their staging is scoped: a
+    refactor that drops a scope fails here."""
+    lowered = _lowered_for_chip(app, monkeypatch)
+    ops = _full_op_names(lowered.as_text(dialect="hlo", debug_info=True))
+    roots = tuple(f"{r}/" for r in telemetry.SCOPE_ROOTS)
+    unscoped = collections.Counter(
+        (opc, name) for opc, operands, name in ops
+        if opc not in _PLUMBING
+        and not (opc == "broadcast" and operands.startswith("constant"))
+        and not any(r in name for r in roots))
+    assert not unscoped, f"ops under no scope root: {dict(unscoped)}"
+    names = [name for _, _, name in ops]
+    kernels = [n for opc, _, n in ops if opc == "custom-call"]
+    graphs = (("ludwig_chem_stress", "ludwig_lb_step", "ludwig_lc_update")
+              if app == "ludwig" else ("wilson_normal", "cg_update", "cg_xpay"))
+    for g in graphs:
+        assert any(f"launch/{g}/jit({g})/" in n for n in kernels), g
+    for stage in telemetry.STAGE_SCOPES:
+        assert any(f"/{stage}/" in n for n in names), stage
 
 
 # -- unified logging -----------------------------------------------------------
