@@ -54,6 +54,12 @@ interior stage outputs and halo'd local Fields alike — goes through the
 the site count is SAL-tileable and degrades to SOA otherwise (in practice
 only padded local lattices hit the fallback; interior lattices that are
 not tileable already fail at ``init_state``).
+
+In ``step`` SoA Fields are stored nd, ``(ncomp, X, Y, Z)``
+(``Field.from_nd``): the stencils read them as they are, the two site-local
+chains lower on the nd grid and the LB launch stages them with no relayout,
+so a step traces no flat<->nd conversion.  ``step`` takes flat or nd state
+and returns nd.  ``make_sharded_step`` keeps its halo'd local Fields flat.
 """
 
 from __future__ import annotations
@@ -166,8 +172,13 @@ def _fed_body(v, *, a0, gamma, kappa):
 
 def _mkfield(name: str, arr_nd: jnp.ndarray, cfg: LudwigConfig) -> Field:
     lat = tuple(arr_nd.shape[1:])
-    return Field.from_canonical(
-        name, arr_nd, lat, tileable_layout(cfg.layout, lat))
+    return Field.from_nd(name, arr_nd, tileable_layout(cfg.layout, lat))
+
+
+def _nd_state(state: LudwigState) -> LudwigState:
+    """The state with its SoA Fields stored nd (a flat state relayouts
+    once; AoS and AoSoA Fields stay as they are)."""
+    return LudwigState(dist=state.dist.as_nd(), q=state.q.as_nd())
 
 
 # -- stage functions (single-shard periodic) ----------------------------------
@@ -281,6 +292,7 @@ def _cast_state(dist2: Field, like: Field) -> Field:
 
 def step(state: LudwigState, cfg: LudwigConfig) -> LudwigState:
     """One full LC-LB timestep (single shard, periodic)."""
+    state = _nd_state(state)
     with telemetry.scope("ludwig/gradients"):
         q_nd = state.q.canonical_nd()
     dq_nd, lapq_nd = stage_gradients(q_nd)
@@ -318,6 +330,7 @@ def step_timed(state: LudwigState, cfg: LudwigConfig) -> Tuple[LudwigState, Dict
         t[name] = time.perf_counter() - t0
         return out
 
+    state = _nd_state(state)
     q_nd = state.q.canonical_nd()
     dq_nd, lapq_nd = timed("order_parameter_gradients", stage_gradients, q_nd)
     h, force_nd = timed(
@@ -352,6 +365,7 @@ def tune_step_graphs(cfg: LudwigConfig, state: LudwigState, **tune_kw):
     warm table short-circuits each sweep (info["cached"])."""
     from repro.core import tune
 
+    state = _nd_state(state)
     q_nd = state.q.canonical_nd()
     dq_nd, lapq_nd = stage_gradients(q_nd)
     results = {}
@@ -385,6 +399,7 @@ def tune_step_graphs(cfg: LudwigConfig, state: LudwigState, **tune_kw):
 
 def diagnostics(state: LudwigState, cfg: LudwigConfig) -> Dict[str, jnp.ndarray]:
     """Total mass, momentum, free energy (targetDP reduction API)."""
+    state = _nd_state(state)
     mass = target_sum(state.dist, cfg.target).sum()
     q_nd = state.q.canonical_nd()
     dq_nd = gr.grad_central(q_nd)
@@ -463,8 +478,13 @@ def make_sharded_step(cfg: LudwigConfig, domain: Domain, halo: str = "pre"):
             dq_h = gr.grad_central(qh)
             lapq_h = gr.laplacian(qh)
         # halo'd local Fields keep cfg.layout whenever the padded lattice
-        # stays SAL-tileable (so tuned native-AoSoA plans apply sharded too)
-        mk = lambda name, arr: _mkfield(name, arr, cfg)
+        # stays SAL-tileable (so tuned native-AoSoA plans apply sharded too);
+        # they are stored flat (from_canonical), so the sharded launches
+        # keep the flat lowerings
+        def mk(name, arr):
+            lat = tuple(arr.shape[1:])
+            return Field.from_canonical(
+                name, arr, lat, tileable_layout(cfg.layout, lat))
         with scope("ludwig/chem_stress"):
             qF = mk("q", qh)
             cs = chem_step(

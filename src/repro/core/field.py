@@ -4,6 +4,15 @@ A Field is the targetDP-JAX unit of data: ``ncomp`` components at every site
 of a (possibly multi-dimensional) lattice, physically stored per its Layout
 (paper §3.1).  Kernels (core.target) consume and produce Fields; the kernel
 body only ever sees canonical ``(ncomp, VVL)`` chunks.
+
+An SoA Field is stored in one of two shapes with the same row-major order
+(``comp*nsites + site``, ``Layout.flat_index``): flat ``(ncomp, nsites)``
+(every constructor but :meth:`Field.from_nd`) or nd ``(ncomp, *lattice)``
+(:meth:`Field.from_nd`; :attr:`Field.nd`).  On a TPU the two tile
+differently — components on sublanes in one, the second lattice axis in the
+other — so a reshape between them is a physical copy; each one traced is
+counted under the ``field.relayout`` counter (core.telemetry).  Stencils and
+nd-grid launches (core.fuse) take nd Fields with no copy.
 """
 
 from __future__ import annotations
@@ -17,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import telemetry
-from .layout import Layout, SOA
+from .layout import Layout, LayoutKind, SOA
 
 # every layout view and relayout below issues its device ops under this
 # scope (core.telemetry), so a device trace tells them from kernels
@@ -26,11 +35,22 @@ _RELAYOUT = "field/relayout"
 __all__ = ["Field", "BatchedField"]
 
 
+def _reshaped(arr, shape):
+    """``arr`` in ``shape``; a reshape between the flat and the nd form of a
+    field is a relayout on a TPU, counted under ``field.relayout``."""
+    shape = tuple(shape)
+    if tuple(arr.shape) == shape:
+        return arr
+    telemetry.inc("field.relayout")
+    return arr.reshape(shape)
+
+
 @dataclasses.dataclass
 class Field:
     """ncomp values per site on a lattice, in a given physical layout.
 
-    data      physical jax.Array, shape == layout.physical_shape(ncomp, nsites)
+    data      physical jax.Array, shape == layout.physical_shape(ncomp, nsites),
+              or (ncomp, *lattice) for an nd-stored SoA Field (from_nd)
     lattice   site-space shape, e.g. (nx, ny, nz); nsites = prod(lattice)
     """
 
@@ -55,8 +75,18 @@ class Field:
             canonical = jnp.asarray(canonical)
             ncomp = canonical.shape[0]
             nsites = math.prod(lattice)
-            flat = canonical.reshape(ncomp, nsites)
+            flat = _reshaped(canonical, (ncomp, nsites))
             return cls(name, ncomp, tuple(lattice), layout, layout.pack(flat))
+
+    @classmethod
+    def from_nd(cls, name, arr_nd, layout=SOA):
+        """arr_nd: (ncomp, *lattice).  SoA keeps the site axes (no copy);
+        AoS and AoSoA pack flat, as :meth:`from_canonical`."""
+        arr_nd = jnp.asarray(arr_nd)
+        lattice = tuple(arr_nd.shape[1:])
+        if layout.kind is not LayoutKind.SOA:
+            return cls.from_canonical(name, arr_nd, lattice, layout)
+        return cls(name, arr_nd.shape[0], lattice, layout, arr_nd)
 
     @classmethod
     def from_numpy(cls, name, array_cs, lattice, layout=SOA, dtype=jnp.float32):
@@ -72,16 +102,25 @@ class Field:
     def dtype(self):
         return self.data.dtype
 
+    @property
+    def nd(self) -> bool:
+        """Whether the data keeps its site axes: (ncomp, *lattice) SoA."""
+        return self.layout.kind is LayoutKind.SOA and self.data.ndim > 2
+
     def canonical(self) -> jax.Array:
         """(ncomp, nsites) logical view (layout-independent)."""
         with telemetry.scope(_RELAYOUT):
+            if self.nd:
+                return _reshaped(self.data, (self.ncomp, self.nsites))
             return self.layout.unpack(self.data)
 
     def canonical_nd(self) -> jax.Array:
         """(ncomp, *lattice) logical view — stencil/geometry operations."""
+        if self.nd:
+            return self.data
         with telemetry.scope(_RELAYOUT):
-            return self.layout.unpack(self.data).reshape(
-                (self.ncomp,) + self.lattice)
+            return _reshaped(self.layout.unpack(self.data),
+                             (self.ncomp,) + self.lattice)
 
     def to_numpy(self) -> np.ndarray:
         return np.asarray(self.canonical_nd())
@@ -93,8 +132,24 @@ class Field:
 
     def with_canonical(self, canonical: jax.Array) -> "Field":
         with telemetry.scope(_RELAYOUT):
-            flat = canonical.reshape(self.ncomp, self.nsites)
+            if self.nd:
+                return dataclasses.replace(
+                    self, data=_reshaped(canonical, self.data.shape))
+            flat = _reshaped(canonical, (self.ncomp, self.nsites))
             return dataclasses.replace(self, data=self.layout.pack(flat))
+
+    def as_nd(self) -> "Field":
+        """This SoA Field stored nd (itself if it already is); AoS and
+        AoSoA Fields are returned as they are."""
+        if self.nd or self.layout.kind is not LayoutKind.SOA:
+            return self
+        return self.with_data(self.canonical_nd())
+
+    def as_flat(self) -> "Field":
+        """This Field in its layout's flat physical shape."""
+        if not self.nd:
+            return self
+        return self.with_data(self.canonical())
 
     def as_layout(self, layout: Layout) -> "Field":
         """Relayout (the paper's per-architecture layout switch)."""
@@ -131,6 +186,9 @@ class BatchedField:
     layout: Layout
     data: jax.Array
 
+    # every batch element is stored flat (Field.nd)
+    nd = False
+
     # -- constructors ----------------------------------------------------------
 
     @classmethod
@@ -163,7 +221,7 @@ class BatchedField:
             canonical = jnp.asarray(canonical)
             batch, ncomp = canonical.shape[:2]
             nsites = math.prod(lattice)
-            flat = canonical.reshape(batch, ncomp, nsites)
+            flat = _reshaped(canonical, (batch, ncomp, nsites))
             return cls(name, batch, ncomp, tuple(lattice), layout,
                        jax.vmap(layout.pack)(flat))
 
@@ -193,8 +251,8 @@ class BatchedField:
     def canonical_nd(self) -> jax.Array:
         """(batch, ncomp, *lattice) logical view."""
         with telemetry.scope(_RELAYOUT):
-            return jax.vmap(self.layout.unpack)(self.data).reshape(
-                (self.batch, self.ncomp) + self.lattice)
+            return _reshaped(jax.vmap(self.layout.unpack)(self.data),
+                             (self.batch, self.ncomp) + self.lattice)
 
     def to_numpy(self) -> np.ndarray:
         return np.asarray(self.canonical_nd())

@@ -40,7 +40,11 @@ identical window values: bit-identical outputs, asserted in
 tests/test_view.py.
 
 Site-local-only graphs lower over the flat 1-D site-block grid exactly as
-before.  Graphs containing a stencil stage lower over **x-slabs of the
+before — unless every input Field is nd-stored (``Field.from_nd``): then
+they lower over the nd x-slab (and y/z tile) grid below with no ring,
+each program reading disjoint ``(ncomp, bx, by, bz)`` blocks, and their
+outputs come back nd-stored, so nd state never relayouts to the flat form.
+Graphs containing a stencil stage lower over **x-slabs of the
 halo'd lattice**: every external input is halo-padded by the ring the
 backward width analysis (:meth:`LaunchGraph.halo_widths`) assigns it —
 periodic single-shard via ``core.stencil.halo_pad`` (``halo="periodic"``),
@@ -566,6 +570,14 @@ class LaunchGraph:
     def has_stencil(self) -> bool:
         return any(st.kind == "stencil" for st in self._stages)
 
+    def nd_grid(self, ins: Mapping[str, Field]) -> bool:
+        """Whether launching with ``ins`` lowers on the nd (x-slab, y/z
+        tile) grid: every stencil graph does, and so does a site-local
+        graph whose Field inputs are all nd-stored (``Field.nd``)."""
+        fields = [ins[n] for n in self.external_inputs() if n in ins]
+        return self.has_stencil or (
+            bool(fields) and all(f.nd for f in fields))
+
     def external_inputs(self) -> List[str]:
         """Value names consumed but never produced by an earlier stage, in
         first-use order — what launch() must be fed as Fields or scalars."""
@@ -681,8 +693,10 @@ class LaunchGraph:
             outputs = [v for (_, v, _, _) in self._stages[-1].outs]
         if lattice is None:
             lattice = next(iter(ins.values())).lattice
+        # nd-stored inputs lower on another grid, so they key apart
         inputs = tuple(
-            (n, ins[n].ncomp, str(ins[n].dtype), ins[n].layout.name,
+            (n, ins[n].ncomp, str(ins[n].dtype),
+             ins[n].layout.name + ("/nd" if ins[n].nd else ""),
              tuple(ins[n].lattice))
             for n in ordered_ins)
         # 'pre' and 'overlap' share the input contract (pre-exchanged
@@ -850,6 +864,19 @@ class LaunchGraph:
             )
         ordered_ins = [n for n in ext if n in ins]
         ordered_scalars = [n for n in ext if n in scalars]
+        # storage shape: a launch whose Field inputs are all nd-stored
+        # (Field.from_nd) reads and writes them in place; a mix relayouts
+        # the nd ones flat, so flat launches lower exactly as ever
+        in_nd = tuple(ins[n].nd for n in ordered_ins)
+        if any(in_nd) and not all(in_nd):
+            ins = {n: (f.as_flat() if f.nd else f) for n, f in ins.items()}
+            first = next(iter(ins.values()))
+            in_nd = (False,) * len(ordered_ins)
+        all_nd = bool(in_nd) and all(in_nd)
+        # site-local graphs on nd Fields lower onto the nd (x-slab, y/z
+        # tile) grid with no ring, as stencil graphs do (the rule the
+        # tuner's candidate sweep shares)
+        grid = self.nd_grid(ins)
 
         prod = self._produced()
         if outputs is None:
@@ -912,7 +939,7 @@ class LaunchGraph:
         # planner needs to estimate a candidate's per-program footprint
         # (and auto-tile y/z when whole-staging would blow the budget)
         vmem_views = None
-        if stencil:
+        if grid:
             vmem_views = (
                 tuple((ins[n].ncomp, r, jnp.dtype(ins[n].dtype).itemsize)
                       for n, r in zip(ordered_ins, in_rings)),
@@ -941,13 +968,13 @@ class LaunchGraph:
         if plan is None:  # default policy, or tuned-table miss
             plan = plan_mod.default_plan(
                 config, nsites=nsites, layouts=all_layouts,
-                stencil=stencil, lattice=lattice, halo=halo,
+                stencil=grid, lattice=lattice, halo=halo,
                 vmem_views=vmem_views)
         else:
-            plan = plan_mod.adapt_plan(plan, stencil=stencil, halo=halo)
+            plan = plan_mod.adapt_plan(plan, stencil=grid, halo=halo)
             try:
                 plan.validate(nsites=nsites, lattice=lattice,
-                              layouts=all_layouts, stencil=stencil)
+                              layouts=all_layouts, stencil=grid)
                 if (stencil and plan.engine == "pallas"
                         and plan.view == VIEW_BLOCK):
                     # alignment pre-check: same errors _build_nd would
@@ -973,7 +1000,7 @@ class LaunchGraph:
                     plan.describe(), self.name, lattice, exc_info=True)
                 plan = plan_mod.default_plan(
                     config, nsites=nsites, layouts=all_layouts,
-                    stencil=stencil, lattice=lattice, halo=halo,
+                    stencil=grid, lattice=lattice, halo=halo,
                     vmem_views=vmem_views)
 
         # -- dtype policy: precision becomes a lowering decision ------------
@@ -1014,6 +1041,17 @@ class LaunchGraph:
 
         engine, interpret = plan.engine, plan.interpret
         vvl, bx = plan.vvl, plan.bx
+        # field outputs come back nd-stored when every input is
+        out_nd = tuple(all_nd and out_layouts[o].kind is LayoutKind.SOA
+                       for o in field_outputs)
+        # trace-time engagement counters (core.telemetry): launches lowered
+        # on the nd grid for their storage, and the flat<->nd relayouts a
+        # stencil launch still stages around its kernel
+        if all_nd and not stencil:
+            telemetry.inc("fuse.nd_site_local")
+        if stencil and plan.view != VIEW_BLOCK:
+            telemetry.inc("field.relayout",
+                          in_nd.count(False) + out_nd.count(False))
 
         # launch span (core.telemetry): host-side only — attrs are strings
         # and ints, the traced computation is untouched.  The disabled path
@@ -1037,6 +1075,8 @@ class LaunchGraph:
             lattice,
             batch,
             in_batched,
+            in_nd,
+            out_nd,
             tuple(st.signature() for st in self._stages),
             tuple(
                 (n, ins[n].ncomp, str(ins[n].dtype), ins[n].layout,
@@ -1051,7 +1091,7 @@ class LaunchGraph:
         if fn is None:
             telemetry.inc("fuse.cache_misses")
             tspan.set(cache="miss")
-            build = self._build_nd if stencil else self._build_flat
+            build = self._build_nd if grid else self._build_flat
             build_kw = dict(
                 engine=engine,
                 ordered_ins=ordered_ins,
@@ -1079,8 +1119,8 @@ class LaunchGraph:
                 compute_dt=compute_dt,
                 acc_fold=acc_fold,
             )
-            if stencil:  # only the stencil lowering is view-sensitive
-                build_kw["view"] = plan.view
+            if grid:  # the nd-grid lowering's own knobs
+                build_kw.update(view=plan.view, in_nd=in_nd, out_nd=out_nd)
             fn = build(**build_kw)
             _CACHE[key] = fn
             while len(_CACHE) > _CACHE_CAP:
@@ -1528,6 +1568,8 @@ class LaunchGraph:
         storage_dt=None,
         compute_dt=None,
         acc_fold: Optional[Mapping[str, Tuple[object, bool]]] = None,
+        in_nd: Sequence[bool] = (),
+        out_nd: Sequence[bool] = (),
     ) -> Callable:
         run_nd = self._run_stages_nd
         site_ndim = len(lattice)
@@ -1537,14 +1579,26 @@ class LaunchGraph:
         cast_in = _stage_in_cast(storage_dt, compute_dt, in_dtypes)
         if not in_batched:
             in_batched = (False,) * len(ordered_ins)
+        in_nd = tuple(in_nd) or (False,) * len(ordered_ins)
+        out_nd = tuple(out_nd) or (False,) * len(field_outputs)
+        # a site-local graph (nd-stored inputs, no ring) reads disjoint
+        # (bx, by, bz) blocks of its inputs, as every graph writes outputs
+        blocked = not self.has_stencil
 
-        def to_halo_nd(n, meta, lat, ring, d):
+        def to_halo_nd(n, meta, lat, ring, d, nd_in):
             """Physical data -> canonical (ncomp, *padded_lattice)."""
             ncomp, lay = meta
-            nd = lay.unpack(d).reshape((ncomp,) + tuple(lat))
+            nd = d if nd_in else lay.unpack(d).reshape((ncomp,) + tuple(lat))
             if halo == "periodic" and ring > 0:
                 nd = halo_pad(nd, ring, site_dims)
             return nd
+
+        def to_field(o, a0, nd_out):
+            """An interior (ncomp, *lattice) output -> its storage."""
+            ncomp, dtype = out_info[o]
+            if nd_out:
+                return a0.astype(dtype)
+            return out_layouts[o].pack(a0.reshape(ncomp, -1).astype(dtype))
 
         def red_partial_nd(o, values, partials):
             """As _build_flat's red_partial: policy-accumulated sums refold
@@ -1564,19 +1618,18 @@ class LaunchGraph:
                 if cast_in is not None:
                     datas = cast_in(datas)
                 values = {}
-                for n, meta, lat, ring, d in zip(
-                        ordered_ins, in_meta, in_lats, in_rings, datas):
-                    values[n] = (to_halo_nd(n, meta, lat, ring, d), ring)
+                for n, meta, lat, ring, nd_in, d in zip(
+                        ordered_ins, in_meta, in_lats, in_rings, in_nd,
+                        datas):
+                    values[n] = (to_halo_nd(n, meta, lat, ring, d, nd_in),
+                                 ring)
                 for n, s in zip(ordered_scalars, svals):
                     values[n] = (s, None)
                 values, partials = run_nd(values, site_ndim)
                 res = []
-                for o in field_outputs:
+                for o, nd_out in zip(field_outputs, out_nd):
                     arr, r = values[o]
-                    a0 = _crop_ring(arr, r, 0)
-                    ncomp, dtype = out_info[o]
-                    res.append(out_layouts[o].pack(
-                        a0.reshape(ncomp, -1).astype(dtype)))
+                    res.append(to_field(o, _crop_ring(arr, r, 0), nd_out))
                 res += [red_partial_nd(o, values, partials)
                         for o in red_outputs]
                 return tuple(res)
@@ -1646,7 +1699,13 @@ class LaunchGraph:
                 stage_shapes.append((hsites // lay.sal, ncomp, lay.sal))
             else:
                 stage_shapes.append((ncomp,) + hlat)
-        in_specs = build_halo_in_specs(stage_shapes)
+        if blocked:  # the outputs' disjoint blocks: there is no halo
+            _, in_specs = build_tiled_out_specs(
+                ordered_ins, {n: (nc, dt) for n, (nc, _), dt in
+                              zip(ordered_ins, in_meta, in_dtypes)},
+                lattice, bx, by, bz)
+        else:
+            in_specs = build_halo_in_specs(stage_shapes)
         if tiled:
             # disjoint (bx, by, bz) tiles are directly expressible as
             # Blocked windows; native AoSoA *outputs* degrade to canonical
@@ -1771,7 +1830,9 @@ class LaunchGraph:
                 lead = (0,) if (batch and bat) else ()
                 rows = bx + 2 * ring
                 tstarts, tsizes = tile_tail(ys, zs, ring, hlat)
-                if nat:
+                if blocked:  # the BlockSpec already cut this block
+                    window = r[...]
+                elif nat:
                     # block-coordinate rebase: each x-plane of the halo'd
                     # lattice is row_blocks whole short arrays, so the
                     # window [xs, xs + rows) is a contiguous run on the
@@ -1807,11 +1868,13 @@ class LaunchGraph:
         # tiles (the prefetch chain runs on across batch rows).  Every
         # compiled launch takes this path — whole staging would put the
         # halo'd lattice in VMEM — except native AoSoA inputs
-        # (block-rebased windows are staged whole); interpret mode stages
-        # whole (the plain interpreter has no async-copy semantics).
+        # (block-rebased windows are staged whole) and site-local graphs on
+        # nd fields (disjoint blocks, which Pallas' own pipeline
+        # double-buffers); interpret mode stages whole (the plain
+        # interpreter has no async-copy semantics).
         # Everything downstream of the window (finish_tile) is shared with
         # the staged kernel, so the pipeline is a pure data-movement change.
-        use_dma = not interpret and not any(native_in)
+        use_dma = not interpret and not any(native_in) and not blocked
         n_tiles = nslabs * nty * ntz
         n_lin = max(batch, 1) * n_tiles
         # window dtypes: staged float inputs were cast to the effective
@@ -1917,9 +1980,9 @@ class LaunchGraph:
                 values[n] = (win, ring)
             finish_tile(values, sc_refs, out_refs, acc_refs)
 
-        def stage_in(n, meta, lat, ring, nat, d):
+        def stage_in(n, meta, lat, ring, nat, nd_in, d):
             if not nat:
-                return to_halo_nd(n, meta, lat, ring, d)
+                return to_halo_nd(n, meta, lat, ring, d, nd_in)
             if halo == "periodic" and ring > 0:
                 ncomp, lay = meta
                 return halo_pad_physical(d, lay, ncomp, lat, ring)
@@ -1929,15 +1992,16 @@ class LaunchGraph:
             if cast_in is not None:
                 datas = cast_in(datas)
             staged = []
-            for n, meta, lat, ring, nat, bat, d in zip(
+            for n, meta, lat, ring, nat, nd_in, bat, d in zip(
                     ordered_ins, in_meta, in_lats, in_rings, native_in,
-                    in_batched, datas):
+                    in_nd, in_batched, datas):
                 if batch and bat:  # stage each batch element, stacked
                     staged.append(jax.vmap(
-                        lambda x, _n=n, _m=meta, _l=lat, _r=ring, _na=nat:
-                        stage_in(_n, _m, _l, _r, _na, x))(d))
+                        lambda x, _n=n, _m=meta, _l=lat, _r=ring, _na=nat,
+                        _nd=nd_in: stage_in(_n, _m, _l, _r, _na, _nd, x))(d))
                 else:
-                    staged.append(stage_in(n, meta, lat, ring, nat, d))
+                    staged.append(
+                        stage_in(n, meta, lat, ring, nat, nd_in, d))
             if use_dma:  # the DMA windows read whole (8, 128) tiles
                 for ix, pads in enumerate(stage_pads):
                     if any(pads):
@@ -1957,7 +2021,7 @@ class LaunchGraph:
                         acc = red_spec[red_outputs[idx - nfield]] \
                             .combine_partials(acc, axis=-2)
                     out.append(acc)
-                elif native_out[idx]:  # already the physical AoSoA array
+                elif native_out[idx] or out_nd[idx]:  # already stored
                     out.append(r)
                 else:  # canonical nd -> requested physical layout
                     o = field_outputs[idx]

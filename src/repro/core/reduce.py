@@ -49,6 +49,8 @@ def _reduce_launch(field, config: Optional[TargetConfig],
     config = config or TargetConfig()
     spec = ReduceSpec(op=op)
     batch = int(getattr(field, "batch", 0))
+    if not batch:  # the site-block grid reads flat physical data
+        field = field.as_flat()
     # lowering decisions (vvl conformance, interpret fallback, plan policy)
     # come from the planning layer, like every other launch
     plan = plan_for_launch(config, field.nsites, [field.layout])

@@ -461,6 +461,8 @@ def launch(
         kern = TargetKernel(kern)
     config = config or TargetConfig()
     params = params or {}
+    # the site-block grid reads flat physical data (nd Fields relayout)
+    ins = {k: f.as_flat() for k, f in ins.items()}
     first = next(iter(ins.values()))
     out_specs = _normalize_out_specs(out_specs, first.dtype)
     out_layouts = dict(out_layouts or {})

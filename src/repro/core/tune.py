@@ -315,8 +315,9 @@ def plan_candidates_for(
     layouts = [f.layout for f in ins.values()]
     batch = max((int(getattr(f, "batch", 0)) for f in ins.values()),
                 default=0)
+    grid = graph.nd_grid(ins)
     vmem_views = None
-    if graph.has_stencil:
+    if grid:
         # per-site staging shapes for the VMEM budget model — same
         # derivation LaunchGraph.launch feeds default_plan, so the sweep
         # filters (and logs) exactly the candidates a launch would reject
@@ -340,7 +341,7 @@ def plan_candidates_for(
         )
     in_dtype = str(jnp.dtype(next(iter(ins.values())).dtype))
     return plan_mod.candidate_plans(
-        config, nsites=nsites, layouts=layouts, stencil=graph.has_stencil,
+        config, nsites=nsites, layouts=layouts, stencil=grid,
         lattice=lattice, halo=halo, max_candidates=max_candidates,
         block_view=block_view_for(graph, ins, outputs, halo), batch=batch,
         reduce=bool(graph._reduce_outputs()), vmem_views=vmem_views,

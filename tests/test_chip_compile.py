@@ -172,6 +172,45 @@ def test_ludwig_lc_chain_128_compiles(chip):
     chip(fn, (5, n), (5, n), (9, n), (5, n))
 
 
+@pytest.mark.parametrize("lat", [(128, 128, 128), (8, 16, 192)],
+                         ids=["128cube", "z192"])
+@pytest.mark.parametrize("chain", ["chem_stress", "lc_update"])
+def test_ludwig_nd_site_local_chain_compiles(chip, chain, lat):
+    """The site-local Ludwig chains on nd-stored fields, lowered on the nd
+    block grid under the default VMEM budget: no relayout around the
+    kernel, also where the lane axis (192) is not a multiple of 128."""
+    from repro.apps.ludwig import driver as lud
+    from repro.core import telemetry
+
+    cfg = lud.LudwigConfig(lattice=lat)
+    graph, spec, outputs = {
+        "chem_stress": (lud.chem_stress_graph(cfg),
+                        {"q": 5, "lapq": 5, "dq": 15}, ("h", "sigma")),
+        "lc_update": (lud.lc_update_graph(cfg),
+                      {"q": 5, "h": 5, "w": 9, "adv": 5}, ("q_new",)),
+    }[chain]
+    names = list(spec)
+
+    def fn(*arrs):
+        out = graph.launch(
+            {n: Field.from_nd(n, a) for n, a in zip(names, arrs)},
+            config=COMPILED, outputs=outputs)
+        return tuple(out[o].data for o in outputs)
+
+    views = (tuple((nc, 0, 4) for nc in spec.values()),
+             tuple((graph._produced()[o][0], 4) for o in outputs))
+    plan = plan_mod.default_plan(
+        COMPILED, nsites=math.prod(lat), layouts=[SOA], stencil=True,
+        lattice=lat, vmem_views=views)
+    fp = plan_mod.estimate_vmem_bytes(plan, lattice=lat, in_views=views[0],
+                                      out_views=views[1], tpu=True)
+    assert fp <= plan_mod.resolved_vmem_bytes(COMPILED)
+    before = telemetry.counter_value("fuse.nd_site_local")
+    compiled = chip(fn, *[(nc,) + lat for nc in spec.values()])
+    assert telemetry.counter_value("fuse.nd_site_local") == before + 1
+    assert "copy(" not in compiled.as_text()
+
+
 # -- plan axes off the default path ------------------------------------------------
 
 def test_rsplit_wilson_normal_compiles(chip):

@@ -389,7 +389,13 @@ def test_every_device_op_is_scoped(app, monkeypatch):
               if app == "ludwig" else ("wilson_normal", "cg_update", "cg_xpay"))
     for g in graphs:
         assert any(f"launch/{g}/jit({g})/" in n for n in kernels), g
-    for stage in telemetry.STAGE_SCOPES:
+    stages = telemetry.STAGE_SCOPES
+    if app == "ludwig":
+        # the step's nd-stored fields leave every launch as they are stored:
+        # it stages inputs (the LB launch's halo and tile pads), no outputs
+        assert not any("/stage_out/" in n for n in names)
+        stages = tuple(s for s in stages if s != "stage_out")
+    for stage in stages:
         assert any(f"/{stage}/" in n for n in names), stage
 
 
