@@ -77,6 +77,10 @@ def exchange_dim(
     with telemetry.scope("halo/exchange"):
         lo_interior = _take(x, dim, width, 2 * width)
         hi_interior = _take(x, dim, L - 2 * width, L - width)
+        # counted at trace time: one traced step gives its exchanges and
+        # the bytes each device sends per step
+        telemetry.inc("halo.exchanges")
+        telemetry.inc("halo.bytes", 2 * lo_interior.size * x.dtype.itemsize)
         # my high interior -> right neighbour's low halo
         recv_lo = lax.ppermute(hi_interior, axis_name, perm=fwd)
         # my low interior -> left neighbour's high halo

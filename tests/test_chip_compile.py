@@ -7,7 +7,8 @@ axes, a rank-1 reduction, more VMEM than the core has), so these tests
 compile the main-path fused launches at the sizes ``chip_smoke.py`` runs:
 the Ludwig LB step and LC chain at 128^3, and the MILC normal operator and
 CG update at 24^3x48 — plus the plan axes that compile (split reductions,
-bf16 storage, batched launches, y tiles).
+bf16 storage, batched launches, y tiles), and the whole sharded Ludwig
+step of the four-chip benchmark cell on the described 2x2 mesh.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and test workers import every
@@ -209,6 +210,54 @@ def test_ludwig_nd_site_local_chain_compiles(chip, chain, lat):
     compiled = chip(fn, *[(nc,) + lat for nc in spec.values()])
     assert telemetry.counter_value("fuse.nd_site_local") == before + 1
     assert "copy(" not in compiled.as_text()
+
+
+def test_ludwig_sharded_step_2x2_compiles(topo, monkeypatch):
+    """The whole decomposed Ludwig step of the four-chip benchmark cell:
+    384x384x192 over the described 2x2 mesh, 192^3 per chip, halo="pre".
+    The exchanges lower to collective-permutes beside the Mosaic kernels,
+    the step fits well inside a chip, and the halo counters give the
+    bytes each chip sends: q at width 2 on its (5, 196, 196, 192) halo'd
+    array, dist and the force at width 1 padded in every dim
+    ((19|3, 194, 194, 194)), u at width 1 ((3, 194, 194, 192)); each
+    exchanged dim sends its two faces, x first, then y with the x halo."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.apps.ludwig import driver as lud
+    from repro.core import telemetry
+    from repro.lattice import Domain
+
+    monkeypatch.setattr(plan_mod, "_device_kind",
+                        lambda: topo.devices[0].device_kind)
+    lat = (384, 384, 192)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("x", "y"))
+    dom = Domain(global_shape=lat, mesh=mesh, dim_axes=("x", "y", None),
+                 halo=2)
+    cfg = lud.LudwigConfig(lattice=lat, target=COMPILED)
+    args = [jax.ShapeDtypeStruct((nc,) + lat, jnp.float32,
+                                 sharding=dom.sharding()) for nc in (19, 5)]
+    before = telemetry.counters("halo.")
+    lowered = lud.make_sharded_step(cfg, dom, halo="pre").lower(*args)
+    after = telemetry.counters("halo.")
+    assert after["halo.exchanges"] - before.get("halo.exchanges", 0) == 8
+
+    def faces(ncomp, w, x, y, z):
+        return 2 * ncomp * w * (y * z + x * z) * 4
+
+    want = (faces(5, 2, 196, 196, 192) + faces(19, 1, 194, 194, 194)
+            + faces(3, 1, 194, 194, 194) + faces(3, 1, 194, 194, 192))
+    assert want == 21_056_896
+    assert after["halo.bytes"] - before.get("halo.bytes", 0) == want
+
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
+    mem = compiled.memory_analysis()
+    per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                  + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert per_device < 8e9
 
 
 # -- plan axes off the default path ------------------------------------------------

@@ -509,3 +509,41 @@ def test_pipeline_step_spans():
     assert [e["attrs"]["step"] for e in steps] == [0, 1, 2]
     (blk,) = telemetry.events("pipeline/incstep.block")
     assert blk["attrs"]["steps"] == 3
+
+
+# -- halo exchange counters ------------------------------------------------------
+
+@pytest.mark.parametrize("width", [1, 2])
+def test_halo_exchange_counts_bytes_sent_at_trace_time(width):
+    """Each exchanged dim counts one exchange and both face slabs it
+    sends, once per trace: the jitted calls that reuse the trace add
+    nothing, and the counted volume is the one from the padded shape."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import compat, halo
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("x", "y"))
+    dec = ((1, "x", 1), (2, "y", 1))
+    shape = (3, 6 + 2 * width, 5 + 2 * width, 4)
+    fn = jax.jit(compat.shard_map(
+        lambda a: halo.exchange(a, dec, width=width), mesh=mesh,
+        in_specs=P(None, "x", "y", None), out_specs=P(None, "x", "y", None)))
+    x = jax.device_put(jnp.arange(np.prod(shape), dtype=jnp.float32)
+                       .reshape(shape),
+                       NamedSharding(mesh, P(None, "x", "y", None)))
+    for _ in range(3):
+        out = fn(x)
+    # one rank per axis: the exchange is the periodic wrap of the interior
+    want = np.asarray(x)
+    for dim in (1, 2):
+        n = want.shape[dim]
+        inner = np.take(want, range(width, n - width), axis=dim)
+        want = np.concatenate([np.take(inner, range(-width, 0), axis=dim),
+                               inner, np.take(inner, range(width), axis=dim)],
+                              axis=dim)
+    np.testing.assert_array_equal(np.asarray(out), want)
+    _, X, Y, Z = shape
+    assert telemetry.counters("halo.") == {
+        "halo.exchanges": 2,
+        "halo.bytes": 2 * 3 * width * (Y * Z + X * Z) * 4}
