@@ -59,12 +59,14 @@ In ``step`` SoA Fields are stored nd, ``(ncomp, X, Y, Z)``
 (``Field.from_nd``): the stencils read them as they are, the two site-local
 chains lower on the nd grid and the LB launch stages them with no relayout,
 so a step traces no flat<->nd conversion.  ``step`` takes flat or nd state
-and returns nd.  ``make_sharded_step`` keeps its halo'd local Fields flat.
+and returns nd.  ``make_sharded_step`` stores its halo'd and interior local
+Fields nd the same way, so under ``halo="pre"`` it traces no relayout either.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Tuple
 
@@ -478,13 +480,10 @@ def make_sharded_step(cfg: LudwigConfig, domain: Domain, halo: str = "pre"):
             dq_h = gr.grad_central(qh)
             lapq_h = gr.laplacian(qh)
         # halo'd local Fields keep cfg.layout whenever the padded lattice
-        # stays SAL-tileable (so tuned native-AoSoA plans apply sharded too);
-        # they are stored flat (from_canonical), so the sharded launches
-        # keep the flat lowerings
-        def mk(name, arr):
-            lat = tuple(arr.shape[1:])
-            return Field.from_canonical(
-                name, arr, lat, tileable_layout(cfg.layout, lat))
+        # stays SAL-tileable (so tuned native-AoSoA plans apply sharded too)
+        # and are stored nd as in ``step``: the chains lower on the nd grid
+        # and the LB launch stages dist/force with no relayout
+        mk = functools.partial(_mkfield, cfg=cfg)
         with scope("ludwig/chem_stress"):
             qF = mk("q", qh)
             cs = chem_step(

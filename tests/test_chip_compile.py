@@ -51,17 +51,29 @@ def topo():
 @pytest.fixture
 def chip(topo, monkeypatch):
     """A compile helper for one described v5e chip: takes a function of
-    fp32 arrays and their shapes, returns the compiled executable."""
+    fp32 arrays and their shapes, returns the compiled executable.
+
+    ``row_major`` fixes the arguments' and results' layout to row-major,
+    as arrays reach a launch inside a step: otherwise the compiler may lay
+    out an entry argument whose y is not a multiple of 8 with y minor, and
+    copy it to and from the kernel's row-major layout."""
+    from jax.experimental.layout import Format, Layout
     from jax.sharding import SingleDeviceSharding
 
     dev = topo.devices[0]
     monkeypatch.setattr(plan_mod, "_device_kind", lambda: dev.device_kind)
     one_chip = SingleDeviceSharding(dev)
 
-    def compile_(fn, *shapes):
-        args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    def compile_(fn, *shapes, row_major=False):
+        def place(s):
+            return (Format(Layout(tuple(range(len(s)))), one_chip)
+                    if row_major else one_chip)
+
+        args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=place(s))
                 for s in shapes]
-        compiled = jax.jit(fn).lower(*args).compile()
+        jitted = (jax.jit(fn, out_shardings=place(shapes[0])) if row_major
+                  else jax.jit(fn))
+        compiled = jitted.lower(*args).compile()
         assert "tpu_custom_call" in compiled.as_text()  # a Mosaic kernel
         return compiled
 
@@ -173,13 +185,16 @@ def test_ludwig_lc_chain_128_compiles(chip):
     chip(fn, (5, n), (5, n), (9, n), (5, n))
 
 
-@pytest.mark.parametrize("lat", [(128, 128, 128), (8, 16, 192)],
-                         ids=["128cube", "z192"])
+@pytest.mark.parametrize("lat", [(128, 128, 128), (8, 16, 192),
+                                 (196, 196, 192)],
+                         ids=["128cube", "z192", "halo196"])
 @pytest.mark.parametrize("chain", ["chem_stress", "lc_update"])
 def test_ludwig_nd_site_local_chain_compiles(chip, chain, lat):
     """The site-local Ludwig chains on nd-stored fields, lowered on the nd
     block grid under the default VMEM budget: no relayout around the
-    kernel, also where the lane axis (192) is not a multiple of 128."""
+    kernel, also where the lane axis (192) is not a multiple of 128, and
+    on the four-chip cell's halo'd local lattice, whose y (196) is not a
+    multiple of 8."""
     from repro.apps.ludwig import driver as lud
     from repro.core import telemetry
 
@@ -207,8 +222,33 @@ def test_ludwig_nd_site_local_chain_compiles(chip, chain, lat):
                                       out_views=views[1], tpu=True)
     assert fp <= plan_mod.resolved_vmem_bytes(COMPILED)
     before = telemetry.counter_value("fuse.nd_site_local")
-    compiled = chip(fn, *[(nc,) + lat for nc in spec.values()])
+    compiled = chip(fn, *[(nc,) + lat for nc in spec.values()],
+                    row_major=True)
     assert telemetry.counter_value("fuse.nd_site_local") == before + 1
+    assert "copy(" not in compiled.as_text()
+
+
+def test_lb_step_pre_halo_nd_194_compiles(chip):
+    """The fused LB launch as the four-chip step feeds it: nd dist and
+    force pre-padded by the halo ring to (194, 194, 194) under
+    halo="pre", read in place (no relayout) and returned nd."""
+    from repro.apps.ludwig import driver as lud
+    from repro.core import telemetry
+
+    graph = lud.lb_step_graph(lud.LudwigConfig(lattice=(192, 192, 192)))
+
+    def fn(dist, force):
+        out = graph.launch(
+            {"dist": Field.from_nd("dist", dist),
+             "force": Field.from_nd("force", force)},
+            config=COMPILED, outputs=("dist2", "u"), halo="pre")
+        assert out["dist2"].nd and out["u"].nd
+        return out["dist2"].data, out["u"].data
+
+    before = telemetry.counter_value("field.relayout")
+    compiled = chip(fn, (19, 194, 194, 194), (3, 194, 194, 194),
+                    row_major=True)
+    assert telemetry.counter_value("field.relayout") == before
     assert "copy(" not in compiled.as_text()
 
 
@@ -220,7 +260,8 @@ def test_ludwig_sharded_step_2x2_compiles(topo, monkeypatch):
     bytes each chip sends: q at width 2 on its (5, 196, 196, 192) halo'd
     array, dist and the force at width 1 padded in every dim
     ((19|3, 194, 194, 194)), u at width 1 ((3, 194, 194, 192)); each
-    exchanged dim sends its two faces, x first, then y with the x halo."""
+    exchanged dim sends its two faces, x first, then y with the x halo.
+    The local Fields are stored nd, so the step stages no relayout."""
     import numpy as np
     from jax.sharding import Mesh
 
@@ -238,9 +279,14 @@ def test_ludwig_sharded_step_2x2_compiles(topo, monkeypatch):
     args = [jax.ShapeDtypeStruct((nc,) + lat, jnp.float32,
                                  sharding=dom.sharding()) for nc in (19, 5)]
     before = telemetry.counters("halo.")
+    nd_before = telemetry.counter_value("fuse.nd_site_local")
+    relayouts_before = telemetry.counter_value("field.relayout")
     lowered = lud.make_sharded_step(cfg, dom, halo="pre").lower(*args)
     after = telemetry.counters("halo.")
     assert after["halo.exchanges"] - before.get("halo.exchanges", 0) == 8
+    # the local Fields are nd: both chains on the nd grid, no relayout
+    assert telemetry.counter_value("fuse.nd_site_local") == nd_before + 2
+    assert telemetry.counter_value("field.relayout") == relayouts_before
 
     def faces(ncomp, w, x, y, z):
         return 2 * ncomp * w * (y * z + x * z) * 4

@@ -327,20 +327,47 @@ def test_jitted_step_on_nd_state_traces_no_relayout(config):
     assert telemetry.counter_value("field.relayout") == 2
 
 
-def test_sharded_step_keeps_local_fields_flat():
-    """make_sharded_step stores its halo'd local Fields flat, so none of
-    its site-local launches lowers on the nd grid."""
+def _sharded_step_1x1(cfg):
+    """``make_sharded_step`` on a 1x1 mesh that decomposes x and y: every
+    halo is the local wrap, and the halo'd local lattices have y + 4
+    (chains) and y + 2 (LB launch), which are no multiple of 8."""
     from repro.core.compat import make_mesh
     from repro.lattice import Domain
-    cfg = lud.LudwigConfig(lattice=(8, 8, 16), target=PALLAS)
     dom = Domain(global_shape=cfg.lattice,
                  mesh=make_mesh((1, 1), ("data", "model")),
                  dim_axes=("data", "model", None), halo=2)
+    return lud.make_sharded_step(cfg, dom, halo="pre")
+
+
+def test_sharded_step_stores_local_fields_nd():
+    """make_sharded_step stores its halo'd and interior local Fields nd:
+    both site-local chains lower on the nd grid, and no launch stages a
+    flat<->nd relayout."""
+    cfg = lud.LudwigConfig(lattice=(8, 8, 16), target=PALLAS)
     st = lud.init_state(cfg, seed=0)
+    d, q = jnp.asarray(st.dist.to_numpy()), jnp.asarray(st.q.to_numpy())
     telemetry.reset_counters("fuse.nd_site_local")
-    lud.make_sharded_step(cfg, dom).lower(
-        jnp.asarray(st.dist.to_numpy()), jnp.asarray(st.q.to_numpy()))
-    assert telemetry.counter_value("fuse.nd_site_local") == 0
+    telemetry.reset_counters("field.")
+    _sharded_step_1x1(cfg).lower(d, q)
+    assert telemetry.counter_value("fuse.nd_site_local") == 2
+    assert telemetry.counter_value("field.relayout") == 0
+
+
+def test_sharded_step_on_nd_local_fields_matches_step():
+    """Two sharded steps on nd local Fields (Pallas) equal two one-chip
+    steps, within the sharded-equals-single tolerance."""
+    cfg = lud.LudwigConfig(lattice=(8, 8, 16), target=PALLAS)
+    st = lud.init_state(cfg, seed=3)
+    sstep = _sharded_step_1x1(cfg)
+    one = jax.jit(functools.partial(lud.step, cfg=cfg))
+    d, q = jnp.asarray(st.dist.to_numpy()), jnp.asarray(st.q.to_numpy())
+    for _ in range(2):
+        d, q = sstep(d, q)
+        st = one(st)
+    np.testing.assert_allclose(np.asarray(d), st.dist.to_numpy(),
+                               rtol=5e-5, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(q), st.q.to_numpy(),
+                               rtol=5e-5, atol=1e-7)
 
 
 def test_diagnostics_and_tuning_take_nd_state(tmp_path):
